@@ -16,6 +16,10 @@ crashing on ``float(stdout.split(...))``.  On a single-core container
 the recorded speedups are honest ~1.0x — the row exists so a real
 multi-core runner records scaling and compare.py gates it from then on.
 
+Virtual devices exist only on the CPU backend, so this bench runs only
+under ``JAX_PLATFORMS=cpu`` and says so otherwise; real chips are
+measured in one process by ``chip_smoke.py --chips4``.
+
 ``BMQSIM_MULTIDEV_SMOKE=1`` shrinks the sweep (qft-12, K=4, 1/2 devices,
 ``smoke_``-prefixed keys) so CI can exercise the harness in seconds.
 """
@@ -65,7 +69,6 @@ def _run_one(mode: str, ndev: int, n: int, k: int, b: int) -> dict:
                         + f" --xla_force_host_platform_device_count={ndev}"
                         ).strip()
     env["PYTHONPATH"] = "src"
-    env.setdefault("JAX_PLATFORMS", "cpu")
     code = textwrap.dedent(_CODE).format(mode=mode, n=n, k=k, b=b, tag=_TAG)
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, env=env, timeout=3600, cwd=_ROOT)
@@ -83,6 +86,10 @@ def _run_one(mode: str, ndev: int, n: int, k: int, b: int) -> dict:
 
 
 def main():
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "bench_multidev sweeps virtual CPU devices and needs "
+            "JAX_PLATFORMS=cpu; run chip_smoke.py --chips4 for real chips")
     smoke = os.environ.get("BMQSIM_MULTIDEV_SMOKE") == "1"
     pre = "smoke_" if smoke else ""
     n, k, b = (12, 4, 8) if smoke else (18, 8, 12)

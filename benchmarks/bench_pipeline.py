@@ -118,46 +118,11 @@ def _measure_depths(name: str, n: int, local_bits: int,
     return best
 
 
-def _depth_sweep_isolated(name: str, n: int, local_bits: int,
-                          prefix: str = "") -> None:
-    """Run the depth sweep in a FRESH interpreter and re-emit its rows.
-
-    By the time the suite reaches this bench the process has hours of
-    allocator churn and jit-cache pressure behind it, which reproducibly
-    skews the small (~10%) depth ratios that the ``depth_2_speedup``
-    gate protects; a clean process measures the schedule, not the
-    process history.  Falls back to in-process when spawning fails.
-    """
-    import json
-    import os
-    import subprocess
-    import sys
-
-    code = ("import json\n"
-            "from benchmarks.bench_pipeline import _measure_depths\n"
-            f"best = _measure_depths({name!r}, {n}, {local_bits})\n"
-            "print('SWEEP ' + json.dumps(best))\n")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, check=True,
-                             timeout=1800).stdout
-        payload = [ln for ln in out.splitlines() if ln.startswith("SWEEP ")]
-        best = {int(k): v for k, v in json.loads(payload[-1][6:]).items()}
-    except (subprocess.SubprocessError, OSError, IndexError):
-        _depth_sweep(name, n, local_bits, prefix)
-        return
-    for d in sorted(best):
-        emit("pipeline", f"{prefix}depth_{d}_s", best[d])
-        emit("pipeline", f"{prefix}depth_{d}_speedup", best[1] / best[d])
-
-
 def main():
     # Fig. 12 depth sweep at the paper layout and at a cache-exceeding
     # qft-18 layout; the *_speedup rows feed the compare.py gate
-    _depth_sweep_isolated("qft", 14, 7)
-    _depth_sweep_isolated("qft", 18, 11, prefix="qft18_")
+    _depth_sweep("qft", 14, 7)
+    _depth_sweep("qft", 18, 11, prefix="qft18_")
 
     # codec backend: boundary bytes per stage, host vs device
     stats_by_backend = {}

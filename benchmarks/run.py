@@ -18,6 +18,7 @@ from . import (bench_fidelity, bench_max_qubits, bench_memory,
                bench_pipeline, bench_sc19, bench_serve, bench_session,
                bench_sim_time, bench_tuning)
 from .common import drain_rows
+from repro.launch.cache import use_compile_cache
 
 BENCHES = {
     "max_qubits": bench_max_qubits.main,     # Table 2
@@ -44,6 +45,7 @@ def main(argv=None) -> int:
                     help="also write a machine-readable JSON dump "
                          "(convention: BENCH_<date>.json)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     print("bench,key,value")
     report: dict = {"benches": {}, "unix_time": time.time()}

@@ -287,7 +287,7 @@ def verify_plan(plan: ExecutionPlan, circuit=None) -> list[PlanFinding]:
 
         # stage-fn key: the engine compiles (or reuses) exactly this key;
         # a stale key silently runs the wrong jitted function
-        key = (sp.plan, nv, plan.use_kernel, plan.gate_schedule, plan.interpret)
+        key = (sp.plan, nv, plan.use_kernel, plan.gate_schedule)
         if sp.stagefn_key != key:
             msg = f"stagefn_key {sp.stagefn_key!r} != expected {key!r}"
             err("stagefn-key", msg, i)

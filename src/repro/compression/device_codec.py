@@ -8,7 +8,6 @@ format is exact-sized:
     codes       (n,)              uint16  — quantizer output, packed into
                                             u16-pair words by
                                             ``kernels.pack.pack_codes_tiles``
-                                            and bitcast for transfer
     sign_bytes  (4*ceil(n/32),)   uint8   — ballot-packed sign bits
                                             (LSB-first, fused into
                                             ``quantize_tiles``)
@@ -16,6 +15,12 @@ format is exact-sized:
 
 i.e. ~2.13 bytes per element instead of 4 (f32) — ~4.25 vs 8 bytes per
 complex amplitude — before the host lossless stage shrinks it further.
+On the device both streams stay the kernels' int32 words, cut to the
+ceil(n/2) and ceil(n/32) words that hold n elements; the host views the
+fetched words as uint16 / uint8 (a little-endian view, no copy) and
+builds the words for a decode the same way.  No 16- or 8-bit array ever
+exists on the device: a TPU lays out such narrow arrays padded many
+times over.
 
 Encode path (device -> store):   ``encode_group_device`` dispatches the
 quantize + pack kernels per block, ``wire_to_segments`` runs the host
@@ -41,7 +46,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ..faults import fault_point
 from ..kernels import pack as _pk
@@ -61,10 +65,12 @@ _LANES = 128
 
 
 class PlaneWire(NamedTuple):
-    """One plane's boundary-crossing representation (device or host arrays)."""
+    """One plane's boundary-crossing representation: int32 words on the
+    device, their uint16 / uint8 views on the host.  For an odd n the
+    code view holds one zero pad code; readers take the first n."""
 
-    codes: jax.Array | np.ndarray        # (n,) u16
-    sign_bytes: jax.Array | np.ndarray   # (4*ceil(n/32),) u8, LSB-first
+    codes: jax.Array | np.ndarray        # host: (2*ceil(n/2),) u16
+    sign_bytes: jax.Array | np.ndarray   # host: (4*ceil(n/32),) u8, LSB-first
     l_max: jax.Array | np.ndarray        # (1, 1) f32
 
     @property
@@ -81,15 +87,22 @@ def plane_geometry(n: int) -> tuple[int, int]:
 
 def sign_wire_bytes(n: int) -> int:
     """Sign-bitmap wire size: whole ballot words, 4 bytes per 32 elements."""
-    return 4 * ((n + 31) // 32)
+    return 4 * _sign_words(n)
+
+
+def _code_words(n: int) -> int:
+    return (n + 1) // 2
+
+
+def _sign_words(n: int) -> int:
+    return (n + 31) // 32
 
 
 # --------------------------------------------------------------------------
 # encode: device kernels -> wire -> host lossless stage
 # --------------------------------------------------------------------------
 
-def _encode_plane_dev(x: jax.Array, pad: int, step: float,
-                      interpret: bool) -> PlaneWire:
+def _encode_plane_dev(x: jax.Array, pad: int, step: float) -> PlaneWire:
     n = x.shape[0]
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad,), jnp.float32)])
@@ -98,19 +111,14 @@ def _encode_plane_dev(x: jax.Array, pad: int, step: float,
     l_max = jnp.where(max_abs > 0,
                       jnp.log2(jnp.maximum(max_abs, 1e-45)), 0.0)
     l_max = l_max.reshape(1, 1).astype(jnp.float32)
-    codes, packed_signs, _flags = _qz.quantize_tiles(x2d, l_max, step,
-                                                     interpret=interpret)
-    packed_codes = _pk.pack_codes_tiles(codes, interpret=interpret)
-    codes_u16 = lax.bitcast_convert_type(packed_codes,
-                                         jnp.uint16).reshape(-1)[:n]
-    sign_bytes = lax.bitcast_convert_type(
-        packed_signs, jnp.uint8).reshape(-1)[:sign_wire_bytes(n)]
-    return PlaneWire(codes_u16, sign_bytes, l_max)
+    codes, packed_signs, _flags = _qz.quantize_tiles(x2d, l_max, step)
+    return PlaneWire(
+        _pk.pack_codes_tiles(codes).reshape(-1)[:_code_words(n)],
+        packed_signs.reshape(-1)[:_sign_words(n)], l_max)
 
 
-@partial(jax.jit, static_argnames=("n_blocks", "step", "interpret"))
-def _encode_group_jit(planes: jax.Array, n_blocks: int, step: float,
-                      interpret: bool):
+@partial(jax.jit, static_argnames=("n_blocks", "step"))
+def _encode_group_jit(planes: jax.Array, n_blocks: int, step: float):
     bsz = planes.shape[1] // n_blocks
     _, pad = plane_geometry(bsz)
     re = planes[0].reshape(n_blocks, bsz)
@@ -118,14 +126,14 @@ def _encode_group_jit(planes: jax.Array, n_blocks: int, step: float,
     out = []
     for i in range(n_blocks):
         out.append((
-            _encode_plane_dev(re[i], pad, step, interpret),
-            _encode_plane_dev(im[i], pad, step, interpret),
+            _encode_plane_dev(re[i], pad, step),
+            _encode_plane_dev(im[i], pad, step),
         ))
     return tuple(out)
 
 
 def encode_group_planes(planes: jax.Array, n_blocks: int,
-                        params: PwRelParams, *, interpret: bool = True):
+                        params: PwRelParams):
     """Dispatch the lossy encode of a planes-resident group on its device.
 
     Args:
@@ -140,16 +148,15 @@ def encode_group_planes(planes: jax.Array, n_blocks: int,
         arrays, dispatched asynchronously (nothing is fetched yet).
     """
     return _encode_group_jit(jnp.asarray(planes, jnp.float32), n_blocks,
-                             log_step(params.b_r), interpret)
+                             log_step(params.b_r))
 
 
-def encode_group_device(amps: jax.Array, n_blocks: int, params: PwRelParams,
-                        *, interpret: bool = True):
+def encode_group_device(amps: jax.Array, n_blocks: int, params: PwRelParams):
     """Complex-array convenience over :func:`encode_group_planes` —
     identical stored bytes (a complex64's components are already f32)."""
     fault_point("codec.encode")
     planes = jnp.stack([jnp.real(amps), jnp.imag(amps)]).astype(jnp.float32)
-    return encode_group_planes(planes, n_blocks, params, interpret=interpret)
+    return encode_group_planes(planes, n_blocks, params)
 
 
 def fetch_group_wire(encoded) -> tuple[list[tuple[PlaneWire, PlaneWire]], int]:
@@ -161,7 +168,8 @@ def fetch_group_wire(encoded) -> tuple[list[tuple[PlaneWire, PlaneWire]], int]:
     for re_w, im_w in encoded:
         host_pair = []
         for w in (re_w, im_w):
-            h = PlaneWire(np.asarray(w.codes), np.asarray(w.sign_bytes),
+            h = PlaneWire(np.asarray(w.codes).view("<u2").reshape(-1),
+                          np.asarray(w.sign_bytes).view(np.uint8).reshape(-1),
                           np.asarray(w.l_max))
             moved += h.nbytes
             host_pair.append(h)
@@ -171,7 +179,7 @@ def fetch_group_wire(encoded) -> tuple[list[tuple[PlaneWire, PlaneWire]], int]:
 
 def _wire_plane_to_segments(w: PlaneWire, n: int,
                             prescan: bool) -> PlaneSegments:
-    u16 = np.asarray(w.codes, dtype="<u2")
+    u16 = np.asarray(w.codes, dtype="<u2")[:n]
     bits = np.unpackbits(np.asarray(w.sign_bytes, dtype=np.uint8),
                          bitorder="little", count=n).astype(bool)
     return PlaneSegments(l_max=float(np.asarray(w.l_max).reshape(())),
@@ -181,7 +189,7 @@ def _wire_plane_to_segments(w: PlaneWire, n: int,
 
 def _wire_plane_to_f32(w: PlaneWire, n: int, step: float) -> np.ndarray:
     """Pure-numpy dequantize of a host wire plane (pwrel.py math, GIL-free)."""
-    codes = np.asarray(w.codes, dtype="<u2")
+    codes = np.asarray(w.codes, dtype="<u2")[:n]
     bits = np.unpackbits(np.asarray(w.sign_bytes, dtype=np.uint8),
                          bitorder="little", count=n).astype(bool)
     d = np.float32(CODE_MAX) - codes.astype(np.float32)
@@ -238,36 +246,35 @@ def segments_to_wire(seg: BlockSegments) -> tuple[PlaneWire, PlaneWire]:
             _segments_plane_to_wire(seg.im, seg.n_amps, seg.prescan))
 
 
-def _decode_plane_dev(codes_u16: jax.Array, sign_bytes: jax.Array,
-                      l_max: jax.Array, n: int, step: float,
-                      interpret: bool) -> jax.Array:
-    rows, pad = plane_geometry(n)
+def _host_words(a: np.ndarray, count: int) -> np.ndarray:
+    """A host wire stream as ``count`` int32 words (zero-padded bytes)."""
+    raw = np.ascontiguousarray(a).view(np.uint8).reshape(-1)
+    words = np.zeros(count * 4, np.uint8)
+    words[:raw.size] = raw
+    return words.view("<i4")
+
+
+def _word_rows(words: jax.Array, rows: int, width: int) -> jax.Array:
+    """Wire words -> the (rows, width) tile the unpack kernels read."""
+    pad = rows * width - words.shape[0]
     if pad:
-        codes_u16 = jnp.concatenate(
-            [codes_u16, jnp.zeros((pad,), jnp.uint16)])
-    packed_codes = lax.bitcast_convert_type(
-        codes_u16.reshape(rows * (_LANES // 2), 2),
-        jnp.int32).reshape(rows, _LANES // 2)
-    spad = rows * 16 - sign_bytes.shape[0]
-    if spad:
-        sign_bytes = jnp.concatenate(
-            [sign_bytes, jnp.zeros((spad,), jnp.uint8)])
-    packed_signs = lax.bitcast_convert_type(
-        sign_bytes.reshape(rows, 4, 4), jnp.int32)
-    codes = _pk.unpack_codes_tiles(packed_codes, interpret=interpret)
-    plane = _qz.dequantize_tiles(codes, packed_signs, l_max, step,
-                                 interpret=interpret)
-    return plane.reshape(-1)[:n]
+        words = jnp.concatenate([words, jnp.zeros((pad,), jnp.int32)])
+    return words.reshape(rows, width)
 
 
-@partial(jax.jit, static_argnames=("n", "step", "interpret"))
-def _decode_blocks_jit(codes, sign_bytes, l_max, n: int, step: float,
-                       interpret: bool):
-    """codes (2k, n) u16 / sign_bytes (2k, s) u8 / l_max (2k, 1, 1) f32,
-    planes in block order [re0, im0, re1, im1, ...] -> (k, 2, n) f32."""
+@partial(jax.jit, static_argnames=("n", "step"))
+def _decode_blocks_jit(codes, signs, l_max, n: int, step: float):
+    """codes (2k, ceil(n/2)) / signs (2k, ceil(n/32)) i32 words / l_max
+    (2k, 1, 1) f32, planes in block order [re0, im0, re1, im1, ...] ->
+    (k, 2, n) f32."""
     k2 = codes.shape[0]
-    planes = [_decode_plane_dev(codes[i], sign_bytes[i], l_max[i], n, step,
-                                interpret) for i in range(k2)]
+    rows, _ = plane_geometry(n)
+    planes = [
+        _qz.dequantize_tiles(
+            _pk.unpack_codes_tiles(_word_rows(codes[i], rows, _LANES // 2)),
+            _word_rows(signs[i], rows, _LANES // 32),
+            l_max[i], step).reshape(-1)[:n]
+        for i in range(k2)]
     return jnp.stack(planes).reshape(k2 // 2, 2, n)
 
 
@@ -277,8 +284,8 @@ def _planes_to_complex(planes: jax.Array) -> jax.Array:
     return (planes[..., 0, :] + 1j * planes[..., 1, :]).astype(jnp.complex64)
 
 
-def decode_blocks_planes(pairs: list, n: int, params: PwRelParams, device,
-                         *, interpret: bool = True) -> tuple[jax.Array, int]:
+def decode_blocks_planes(pairs: list, n: int, params: PwRelParams,
+                         device) -> tuple[jax.Array, int]:
     """Ship several blocks' wire arrays to ``device`` in three batched
     transfers and decode them in one kernel dispatch.
 
@@ -291,33 +298,30 @@ def decode_blocks_planes(pairs: list, n: int, params: PwRelParams, device,
     compute of the previous group (§4.2).
     """
     planes = [w for pair in pairs for w in pair]
-    codes = np.stack([np.asarray(w.codes) for w in planes])
-    sign_bytes = np.stack([np.asarray(w.sign_bytes) for w in planes])
+    codes = np.stack([_host_words(w.codes, _code_words(n)) for w in planes])
+    signs = np.stack([_host_words(w.sign_bytes, _sign_words(n))
+                      for w in planes])
     l_max = np.stack([np.asarray(w.l_max) for w in planes])
-    moved = codes.nbytes + sign_bytes.nbytes + l_max.nbytes
+    moved = codes.nbytes + signs.nbytes + l_max.nbytes
     blocks = _decode_blocks_jit(
-        jax.device_put(codes, device), jax.device_put(sign_bytes, device),
-        jax.device_put(l_max, device), n=n, step=log_step(params.b_r),
-        interpret=interpret)
+        jax.device_put(codes, device), jax.device_put(signs, device),
+        jax.device_put(l_max, device), n=n, step=log_step(params.b_r))
     return blocks, moved
 
 
-def decode_blocks_device(pairs: list, n: int, params: PwRelParams, device,
-                         *, interpret: bool = True) -> tuple[jax.Array, int]:
+def decode_blocks_device(pairs: list, n: int, params: PwRelParams,
+                         device) -> tuple[jax.Array, int]:
     """Complex-array convenience over :func:`decode_blocks_planes`.
 
     Returns (device complex64 blocks (len(pairs), n), bytes moved h2d).
     """
     fault_point("codec.decode")
-    planes, moved = decode_blocks_planes(pairs, n, params, device,
-                                         interpret=interpret)
+    planes, moved = decode_blocks_planes(pairs, n, params, device)
     return _planes_to_complex(planes), moved
 
 
 def decode_block_device(pair: tuple[PlaneWire, PlaneWire], n: int,
-                        params: PwRelParams, device,
-                        *, interpret: bool = True) -> tuple[jax.Array, int]:
+                        params: PwRelParams, device) -> tuple[jax.Array, int]:
     """Single-block convenience over :func:`decode_blocks_device`."""
-    blocks, moved = decode_blocks_device([pair], n, params, device,
-                                         interpret=interpret)
+    blocks, moved = decode_blocks_device([pair], n, params, device)
     return blocks[0], moved

@@ -20,6 +20,8 @@ normal floats (hypothesis found this edge; tests/test_compression.py).
 
 All arithmetic is float32 so this file doubles as the bit-exact oracle for
 the Pallas quantize/dequantize kernels (kernels/ref.py re-exports it).
+It is the *host* codec, so it always runs on the CPU backend, also in a
+process whose default device is an accelerator.
 """
 from __future__ import annotations
 
@@ -66,10 +68,14 @@ def _quantize(x: jax.Array, step: float):
     return codes, signs, l_max
 
 
+def _on_host(x, dtype) -> jax.Array:
+    return jax.device_put(np.asarray(x, dtype), jax.local_devices(
+        backend="cpu")[0])
+
+
 def quantize_plane(x, params: PwRelParams):
     """f32 plane -> (uint16 codes, bool signs, f32 l_max scalar)."""
-    x = jnp.asarray(x, dtype=jnp.float32)
-    codes, signs, l_max = _quantize(x, params.step)
+    codes, signs, l_max = _quantize(_on_host(x, np.float32), params.step)
     return codes.astype(jnp.uint16), signs, l_max
 
 
@@ -83,6 +89,5 @@ def _dequantize(codes: jax.Array, signs: jax.Array, l_max: jax.Array,
 
 
 def dequantize_plane(codes, signs, l_max, params: PwRelParams):
-    codes = jnp.asarray(codes).astype(jnp.int32)
-    return _dequantize(codes, jnp.asarray(signs), jnp.asarray(l_max, jnp.float32),
-                       params.step)
+    return _dequantize(_on_host(codes, np.int32), _on_host(signs, bool),
+                       _on_host(l_max, np.float32), params.step)

@@ -39,7 +39,6 @@ import numpy as np
 from ..compression.pwrel import PwRelParams
 from ..compression.store import BlockStore
 from ..distributed.lanes import make_lane_mesh, make_lane_shards
-from ..kernels.ops import default_interpret
 from .faults import fault_point
 from .circuit import Circuit, Gate
 from .dense_engine import apply_matrix
@@ -92,7 +91,7 @@ class EngineConfig:
             moves raw 2^(b+m) complex64 group arrays across the
             host↔device boundary; ``"device"`` runs quantize/dequantize +
             bitmap/code packing on the accelerator (Pallas kernels,
-            interpret-mode on CPU) so only packed codes + sign bitmaps +
+            interpreted on CPU) so only packed codes + sign bitmaps +
             scalars cross.  ``"device"`` requires ``compression=True``
             (silently falls back to host otherwise).
         ram_budget_bytes: primary-tier budget of the two-level store (§4.4);
@@ -236,6 +235,9 @@ class SimStats:
     exchange_bytes: int = 0
     n_exchanged_blocks: int = 0
     per_stage_exchange_bytes: list = field(default_factory=list)
+    #: device id -> SV-group rows (one per group and lane) its stage
+    #: waves ran, lifetime — shows whether a mesh run used every device
+    rows_per_device: dict = field(default_factory=dict)
     bytes_per_amp_measured: float = 0.0
     n_transposes_naive: int = 0
     n_transposes_scheduled: int = 0
@@ -329,7 +331,7 @@ def _apply_fused(amps: jax.Array, mats: tuple[jax.Array, ...],
 
 @lru_cache(maxsize=512)
 def _stage_fn(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
-              use_kernel: bool, gate_schedule: bool, interpret: bool):
+              use_kernel: bool, gate_schedule: bool):
     """Jitted planes -> planes group-update function, cached on the stage
     *structure* so stages with identical access patterns share one
     compilation.  The plane stack is donated: the decoded input is dead
@@ -339,16 +341,14 @@ def _stage_fn(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
 
         def fn(planes, *mats):
             return execute_schedule(sched, planes, mats,
-                                    use_kernel=use_kernel,
-                                    interpret=interpret)
+                                    use_kernel=use_kernel)
     elif use_kernel:
         from ..kernels import ops as kops
 
         def fn(planes, *mats):
             amps = planes_to_complex(planes)
             for mat, (vqubits, diag) in zip(mats, plan):
-                amps = kops.apply_fused_gate(amps, mat, vqubits, nv, diag,
-                                             interpret=interpret)
+                amps = kops.apply_fused_gate(amps, mat, vqubits, nv, diag)
             return complex_to_planes(amps)
     else:
         def fn(planes, *mats):
@@ -379,7 +379,7 @@ def _stage_mats(vgates: list[FusedGate],
 
 @lru_cache(maxsize=256)
 def _stage_fn_batch(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
-                    use_kernel: bool, interpret: bool):
+                    use_kernel: bool):
     """Jitted lane-batched (R, 2, 2^nv) -> (R, 2, 2^nv) group update:
     one dispatch covers every lane of a parameter-sweep / trajectory
     batch (lane l's planes contract against lane l's operands).  Cached
@@ -397,14 +397,13 @@ def _stage_fn_batch(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
             d = planes.shape[0] // mats[0].shape[0]
             mats = [jnp.tile(m, (d,) + (1,) * (m.ndim - 1)) for m in mats]
         return execute_schedule_batched(sched, planes, mats,
-                                        use_kernel=use_kernel,
-                                        interpret=interpret)
+                                        use_kernel=use_kernel)
     return jax.jit(fn, donate_argnums=0)
 
 
 @lru_cache(maxsize=256)
 def _stage_fn_wave(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
-                   use_kernel: bool, interpret: bool):
+                   use_kernel: bool):
     """Jitted wave-coalesced (W, 2, 2^nv) -> (W, 2, 2^nv) group update
     for a SINGLE-lane run: every row is a different SV group of the same
     stage, so the one set of stage operands broadcasts across rows
@@ -414,11 +413,8 @@ def _stage_fn_wave(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
     sched = compile_schedule(plan, nv)
 
     def fn(planes, *mats):
-        w = planes.shape[0]
-        bmats = [jnp.broadcast_to(m[None], (w,) + m.shape) for m in mats]
-        return execute_schedule_batched(sched, planes, bmats,
-                                        use_kernel=use_kernel,
-                                        interpret=interpret)
+        return execute_schedule_batched(sched, planes, mats,
+                                        use_kernel=use_kernel, shared=True)
     return jax.jit(fn, donate_argnums=0)
 
 
@@ -530,8 +526,7 @@ class BMQSimEngine:
         self.stats = SimStats(n_qubits=self.n, n_gates=len(circuit))
         self.backend = make_backend(
             config.codec_backend, self.store, self.params, 2 ** self.b,
-            compression=config.compression, prescan=config.prescan,
-            interpret=default_interpret())
+            compression=config.compression, prescan=config.prescan)
 
         t0 = time.perf_counter()
         if plan is not None:
@@ -633,7 +628,6 @@ class BMQSimEngine:
             self._bound.move_to_end(key)
             return cached
         self._check_params(params)
-        interpret = default_interpret()
         bound = []
         for layout, gates in self._stages:
             vgates, plan = fuse_stage(layout, gates,
@@ -641,13 +635,11 @@ class BMQSimEngine:
             mats = _stage_mats(vgates, plan, self.cfg.gate_schedule)
             self.stats.n_fused_unitaries += len(vgates)
             nv = layout.b + layout.m
-            fkey = (plan, nv, self.cfg.use_kernel, self.cfg.gate_schedule,
-                    interpret)
+            fkey = (plan, nv, self.cfg.use_kernel, self.cfg.gate_schedule)
             fn = _stage_fn(*fkey) if plan else None
             # the scheduled path gets the row-batched wave form too (the
             # per-gate path has none — the pipeline runs it sequentially)
-            wave_fn = (_stage_fn_wave(plan, nv, self.cfg.use_kernel,
-                                      interpret)
+            wave_fn = (_stage_fn_wave(plan, nv, self.cfg.use_kernel)
                        if plan and self.cfg.gate_schedule else None)
             sched = compile_schedule(plan, nv) if plan else None
             bound.append(_BoundStage(layout, plan, mats, fkey, fn, sched,
@@ -688,7 +680,6 @@ class BMQSimEngine:
             self._bound_batch.move_to_end(key)
             return cached
         self._validate_bindings(bindings)
-        interpret = default_interpret()
         # one rng per lane, threaded through the stages in circuit order:
         # a lane's realization is identical to circuit.realize(seed)'s
         rngs = [np.random.default_rng(s) if s is not None else None
@@ -701,8 +692,8 @@ class BMQSimEngine:
             mats = _stage_mats_batch(lane_vgates, plan)
             self.stats.n_fused_unitaries += len(plan) * len(bindings)
             nv = layout.b + layout.m
-            fkey = (plan, nv, self.cfg.use_kernel, "batch", interpret)
-            fn = (_stage_fn_batch(plan, nv, self.cfg.use_kernel, interpret)
+            fkey = (plan, nv, self.cfg.use_kernel, "batch")
+            fn = (_stage_fn_batch(plan, nv, self.cfg.use_kernel)
                   if plan else None)
             sched = compile_schedule(plan, nv) if plan else None
             # the batched stage fn is already row-batched (and tiles its
@@ -733,7 +724,6 @@ class BMQSimEngine:
                 self._circuit_fp, self.cfg, self.partition,
                 [(bs.layout, bs.plan) for bs in bound],
                 n_devices=len(self._devices),
-                interpret=default_interpret(),
                 params_key=pkey,
                 auto_tuned=self.auto_tuned)
             self._plans[skey] = plan
@@ -931,6 +921,9 @@ class BMQSimEngine:
         self.stats.t_fetch += pipe.t_fetch
         self.stats.t_compress += pipe.t_store
         self.stats.n_group_phases += pipe.n_group_phases
+        for dev, rows in pipe.rows_per_device.items():
+            self.stats.rows_per_device[dev] = \
+                self.stats.rows_per_device.get(dev, 0) + rows
         self.stats.h2d_bytes += back.h2d_bytes - h2d0
         self.stats.d2h_bytes += back.d2h_bytes - d2h0
         self.stats.n_block_decompressions += back.n_decompressions - dec0
@@ -1067,6 +1060,9 @@ class BMQSimEngine:
         self.stats.t_fetch += pipe.t_fetch
         self.stats.t_compress += pipe.t_store
         self.stats.n_group_phases += pipe.n_group_phases
+        for dev, rows in pipe.rows_per_device.items():
+            self.stats.rows_per_device[dev] = \
+                self.stats.rows_per_device.get(dev, 0) + rows
         self.stats.h2d_bytes += back.h2d_bytes - h2d0
         self.stats.d2h_bytes += back.d2h_bytes - d2h0
         self.stats.n_block_decompressions += back.n_decompressions - dec0

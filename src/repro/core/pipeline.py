@@ -346,11 +346,9 @@ class DeviceCodecBackend(CodecBackend):
 
     name = "device"
 
-    def __init__(self, store, params, bsz, compression=True, prescan=True,
-                 *, interpret: bool = True):
+    def __init__(self, store, params, bsz, compression=True, prescan=True):
         assert compression, "device codec backend requires compression=True"
         super().__init__(store, params, bsz, compression, prescan)
-        self.interpret = interpret
 
     def fetch_group(self, block_ids):
         staged = []
@@ -383,7 +381,7 @@ class DeviceCodecBackend(CodecBackend):
             # the decode lands directly on f32 planes — no complex detour
             blocks, moved = decode_blocks_planes(
                 [staged[i][1] for i in wire_idx], self.bsz, self.params,
-                device, interpret=self.interpret)
+                device)
             self.add_bytes(h2d=moved)
             for j, i in enumerate(wire_idx):
                 parts[i] = blocks[j]
@@ -396,8 +394,7 @@ class DeviceCodecBackend(CodecBackend):
     def dispatch_result(self, planes_dev, n_blocks):
         # the quantize/pack kernels launch here (async); only the wire
         # fetch in await_result blocks
-        return encode_group_planes(planes_dev, n_blocks, self.params,
-                                   interpret=self.interpret)
+        return encode_group_planes(planes_dev, n_blocks, self.params)
 
     def await_result(self, ticket):  # fault-covered: codec.encode
         wire, moved = fetch_group_wire(ticket)    # blocks until done
@@ -431,8 +428,7 @@ class DeviceCodecBackend(CodecBackend):
                     where.append((r, i))
         if wire:
             blocks, moved = decode_blocks_planes(
-                wire, self.bsz, self.params, device,
-                interpret=self.interpret)
+                wire, self.bsz, self.params, device)
             self.add_bytes(h2d=moved)
             for j, (r, i) in enumerate(where):
                 parts[r][i] = blocks[j]
@@ -447,8 +443,7 @@ class DeviceCodecBackend(CodecBackend):
         # dispatch covers every row's blocks and the wire list splits
         # back per row in await_result_batch
         flat = jnp.transpose(planes_dev, (1, 0, 2)).reshape(2, -1)
-        encoded = encode_group_planes(flat, rows * n_blocks, self.params,
-                                      interpret=self.interpret)
+        encoded = encode_group_planes(flat, rows * n_blocks, self.params)
         return (encoded, rows, n_blocks)
 
     def await_result_batch(self, ticket):  # fault-covered: codec.encode
@@ -460,16 +455,15 @@ class DeviceCodecBackend(CodecBackend):
 
 
 def make_backend(name: str, store: BlockStore, params: PwRelParams,
-                 bsz: int, compression: bool = True, prescan: bool = True,
-                 *, interpret: bool = True) -> CodecBackend:
+                 bsz: int, compression: bool = True,
+                 prescan: bool = True) -> CodecBackend:
     """Resolve an ``EngineConfig.codec_backend`` name to a backend.
 
     ``"device"`` degrades to ``"host"`` (with a ``RuntimeWarning``) when
     ``compression`` is off — there is no device half to a raw byte copy.
     """
     if name == "device" and compression:
-        return DeviceCodecBackend(store, params, bsz, compression, prescan,
-                                  interpret=interpret)
+        return DeviceCodecBackend(store, params, bsz, compression, prescan)
     if name == "device":
         warnings.warn(
             "codec_backend='device' requires compression=True; "
@@ -545,6 +539,8 @@ class StagePipeline:
         self.t_fetch = 0.0       # blocking result wait at the d2h boundary
         self.t_store = 0.0                     # guarded-by: _t_lock
         self.n_group_phases = 0
+        #: device id -> group rows its waves ran (lane rows count apiece)
+        self.rows_per_device: dict[int, int] = {}
         self._t_lock = threading.Lock()
         self._dec_pool: ThreadPoolExecutor | None = None
         self._com_pool: ThreadPoolExecutor | None = None
@@ -668,6 +664,9 @@ class StagePipeline:
             return
         items = self._wave_items(block_ids, mats, lane_offsets,
                                  lane_shards, group_devices)
+        for keys, dev, _ in items:
+            self.rows_per_device[dev.id] = \
+                self.rows_per_device.get(dev.id, 0) + len(keys)
         if self._dec_pool is None:
             self._run_waves(items, wave_fn, n_blocks)
             return

@@ -173,7 +173,6 @@ class ExecutionPlan:
     use_kernel: bool
     gate_schedule: bool
     max_fused_qubits: int
-    interpret: bool
     n_devices: int
     memory_budget_bytes: int | None
     auto_tuned: bool
@@ -263,7 +262,6 @@ class ExecutionPlan:
             "use_kernel": self.use_kernel,
             "gate_schedule": self.gate_schedule,
             "max_fused_qubits": self.max_fused_qubits,
-            "interpret": self.interpret,
             "n_devices": self.n_devices,
             "memory_budget_bytes": self.memory_budget_bytes,
             "auto_tuned": self.auto_tuned,
@@ -305,7 +303,7 @@ class ExecutionPlan:
             plan = tuple((tuple(vq), bool(diag)) for vq, diag in sd["plan"])
             layout = GroupLayout(n, b, tuple(sd["inner"]))
             key = (plan, layout.b + layout.m, d["use_kernel"],
-                   d["gate_schedule"], d["interpret"])
+                   d["gate_schedule"])
             stages.append(StagePlan(
                 index=sd["index"], layout=layout,
                 gate_slice=tuple(sd["gate_slice"]), plan=plan,
@@ -326,7 +324,7 @@ class ExecutionPlan:
             prescan=d["prescan"], codec_backend=d["codec_backend"],
             use_kernel=d["use_kernel"], gate_schedule=d["gate_schedule"],
             max_fused_qubits=d["max_fused_qubits"],
-            interpret=d["interpret"], n_devices=d["n_devices"],
+            n_devices=d["n_devices"],
             memory_budget_bytes=d["memory_budget_bytes"],
             auto_tuned=d["auto_tuned"], batch=d.get("batch", 1),
             params_key=tuple(tuple(kv) for kv in d["params_key"]),

@@ -474,7 +474,7 @@ def fuse_stage_lanes(layout: GroupLayout, gates, max_fused: int, bindings,
 
 
 def assemble_plan(circuit_fp: str, cfg, partition, stage_plans,
-                  *, n_devices: int, interpret: bool, params_key: tuple,
+                  *, n_devices: int, params_key: tuple,
                   auto_tuned: bool) -> ExecutionPlan:
     """Freeze a bound engine state into an :class:`ExecutionPlan`.
 
@@ -500,7 +500,7 @@ def assemble_plan(circuit_fp: str, cfg, partition, stage_plans,
             n_t = n_tn = 0
         stage_bytes = (wire * layout.n_groups * layout.blocks_per_group
                        * max(1, cfg.batch))
-        key = (plan, nv, cfg.use_kernel, cfg.gate_schedule, interpret)
+        key = (plan, nv, cfg.use_kernel, cfg.gate_schedule)
         stages.append(StagePlan(
             index=idx, layout=layout,
             gate_slice=(gate_lo, gate_lo + len(st.gates)), plan=plan,
@@ -532,7 +532,7 @@ def assemble_plan(circuit_fp: str, cfg, partition, stage_plans,
         b_r=cfg.b_r, compression=cfg.compression, prescan=cfg.prescan,
         codec_backend=cfg.codec_backend, use_kernel=cfg.use_kernel,
         gate_schedule=cfg.gate_schedule,
-        max_fused_qubits=cfg.max_fused_qubits, interpret=interpret,
+        max_fused_qubits=cfg.max_fused_qubits,
         n_devices=n_devices, memory_budget_bytes=cfg.memory_budget_bytes,
         auto_tuned=auto_tuned, params_key=params_key,
         stages=tuple(stages), predicted=predicted, batch=cfg.batch)

@@ -13,15 +13,16 @@ minimal permutation plan instead, exploiting three facts:
    to move from gate i's layout to gate i+1's layout — one transpose
    (``perm_i^-1 ∘ perm_{i+1}``), not two.  The single inverse permutation
    back to the canonical layout is emitted once, at the end of the stage.
-2. **The major axes are free.** A GEMM only requires the gate's k qubit
-   axes minor-most (qubit 0's axis last); the remaining axes can sit in
-   *any* order.  Keeping them in their current order means consecutive
-   gates on identical qubit sets — and many overlapping sets — need no
-   transpose at all.
+2. **The other axes are free.** A GEMM only requires the gate's k qubit
+   axes to be contiguous (they are placed just above the 7 lowest other
+   axes, so the minor run stays 128 lanes wide); the remaining axes can
+   sit in *any* order.  Keeping them in their current order means
+   consecutive gates on identical qubit sets — and many overlapping
+   sets — need no transpose at all.
 3. **Diagonal unitaries are layout-invariant.** A diagonal gate is an
-   elementwise multiply; in any bit-permuted layout it runs as a
-   broadcast multiply against a (2,)*k diagonal tensor placed on the
-   gate's current axis positions — never a transpose of the group array.
+   elementwise multiply; in any bit-permuted layout each amplitude picks
+   its entry from the bits of its own index — never a transpose of the
+   group array.
 
 The compiled :class:`StageSchedule` is a pure function of the stage plan
 ``((vqubits, diag), ...)`` and ``nv`` — cached with ``lru_cache`` the same
@@ -36,10 +37,15 @@ from dataclasses import dataclass
 from functools import lru_cache
 
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = ["TransposeOp", "GemmOp", "MidGemmOp", "DiagOp", "StageSchedule",
            "compile_schedule", "execute_schedule",
            "execute_schedule_batched", "gate_perm"]
+
+_LANE_BITS = 7                 # a TPU vreg row holds 2^7 = 128 f32 lanes
+_LANES = 1 << _LANE_BITS
+_TILE = 8 * _LANES             # amplitudes in one (8, 128) f32 vreg tile
 
 
 @dataclass(frozen=True)
@@ -86,22 +92,21 @@ class DiagOp:
     """Elementwise multiply by diagonal ``mats[idx]`` ((2, K) planes) in
     the *current* layout — never a transpose.
 
-    When the gate's axes are contiguous in the layout, ``block`` holds
-    ``(p, dmap)``: reshape to (outer, K, inner), select diagonal entries
-    through the compile-time bit permutation ``dmap`` (identity = None),
-    and broadcast along clean axes.  Otherwise ``shape``/``dperm``
-    describe the general nv-axis broadcast of the (2,)*k diagonal tensor.
-    ``minor`` marks the layout where the gate qubits are already
-    minor-most in standard order, so the Pallas ``diag_apply`` row kernel
-    applies directly.
+    ``bits[j]`` is the bit of the flat group index that holds the gate's
+    bit j in that layout, so entry ``sum_j bit(i, bits[j]) << j`` of the
+    diagonal scales amplitude ``i``: one lane-dense pass over the flat
+    planes whatever the layout.  ``minor`` marks the layout where the
+    gate bits are the lowest index bits in standard order, where the
+    Pallas ``diag_apply`` row kernel applies directly.
     """
 
     idx: int
     k: int
-    minor: bool
-    block: tuple[int, tuple[int, ...] | None] | None
-    shape: tuple[int, ...]
-    dperm: tuple[int, ...]
+    bits: tuple[int, ...]
+
+    @property
+    def minor(self) -> bool:
+        return self.bits == tuple(range(self.k))
 
 
 @dataclass(frozen=True)
@@ -151,18 +156,10 @@ def _contiguous_block(vqubits: tuple[int, ...], nv: int,
 
 def _diag_op(idx: int, vqubits: tuple[int, ...], nv: int,
              layout: tuple[int, ...]) -> DiagOp:
-    k = len(vqubits)
-    axes = [nv - 1 - q for q in vqubits]          # canonical axis of bit j
-    pos = [layout.index(a) for a in axes]         # its current position
-    minor = pos == [nv - 1 - j for j in range(k)]
-    block = _contiguous_block(vqubits, nv, layout)
-    # general scattered-axis broadcast fallback
-    order = sorted(range(k), key=lambda j: pos[j])
-    dperm = tuple(k - 1 - j for j in order)
-    shape = [1] * nv
-    for p in pos:
-        shape[p] = 2
-    return DiagOp(idx, k, minor, block, tuple(shape), dperm)
+    # gate bit j's canonical axis is nv-1-q; layout position p is flat bit
+    # nv-1-p
+    bits = tuple(nv - 1 - layout.index(nv - 1 - q) for q in vqubits)
+    return DiagOp(idx, len(vqubits), bits)
 
 
 @lru_cache(maxsize=1024)
@@ -202,11 +199,21 @@ def compile_schedule(plan: tuple[tuple[tuple[int, ...], bool], ...],
                                      inner=1 << (nv - p - k), bmap=bmap))
             continue
         head = [a for a in layout if a not in set(tail)]
-        target = tuple(head + tail)
+        # the gate's axes go just above the layout's 7 lowest other axes,
+        # which stay a dense 128-lane minor run: minor-most gate axes
+        # would leave a K-wide minor axis that a TPU pads to 128 lanes
+        # (and compiles minutes longer).  Groups under 8 such rows keep
+        # the gate minor-most.
+        low = _LANE_BITS if nv >= k + _LANE_BITS + 3 else 0
+        target = tuple(head[:len(head) - low] + tail + head[len(head) - low:])
         ops.append(TransposeOp(tuple(layout.index(a) for a in target)))
         n_transposes += 1
         layout = target
-        ops.append(GemmOp(idx, k))
+        if low:
+            ops.append(MidGemmOp(idx, k, outer=1 << (nv - k - low),
+                                 inner=1 << low))
+        else:
+            ops.append(GemmOp(idx, k))
     if layout != ident:
         ops.append(TransposeOp(tuple(layout.index(a) for a in ident)))
         n_transposes += 1
@@ -214,183 +221,191 @@ def compile_schedule(plan: tuple[tuple[tuple[int, ...], bool], ...],
                          n_transposes_naive=n_naive)
 
 
-def _op_mat(mat, bmap: tuple[int, ...] | None):
-    """(2, K, K) stacked U planes -> (br, bi), bit-permuted when needed."""
-    br, bi = mat[0], mat[1]
+
+
+def _mm(a, b):
+    """f32 matmul at full f32 precision (the TPU default is bf16 passes,
+    which would cost the simulation its fidelity)."""
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+
+def _einsum(spec: str, a, b):
+    return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST)
+
+
+def _collapse(perm: tuple[int, ...]):
+    """Merge the runs of axes that stay adjacent under ``perm``.
+
+    Returns (sizes of the merged source axes, ``perm`` over them): a
+    transpose of the (2,)*nv group becomes one over a few large axes.
+    The TPU compiler takes minutes over a rank-nv array of 2s."""
+    runs: list[list[int]] = []              # [first source axis, length]
+    for a in perm:
+        if runs and runs[-1][0] + runs[-1][1] == a:
+            runs[-1][1] += 1
+        else:
+            runs.append([a, 1])
+    order = sorted(range(len(runs)), key=lambda r: runs[r][0])
+    sizes = tuple(1 << runs[r][1] for r in order)
+    return sizes, tuple(order.index(r) for r in range(len(runs)))
+
+
+def _transpose(x, perm: tuple[int, ...]):
+    """Permute the index bits of flat (L, N) planes as ``perm`` permutes
+    the axes of the (2,)*nv group tensor."""
+    sizes, small = _collapse(perm)
+    lanes = x.shape[0]
+    y = x.reshape((lanes,) + sizes)
+    return y.transpose((0,) + tuple(a + 1 for a in small)).reshape(lanes, -1)
+
+
+def _operand(mat, bmap: tuple[int, ...] | None):
+    """Stacked U planes (..., 2, K, K) -> (br, bi), bit-permuted when
+    needed."""
+    br, bi = mat[..., 0, :, :], mat[..., 1, :, :]
     if bmap is not None:
         idx = jnp.asarray(bmap)
-        br = br[idx][:, idx]
-        bi = bi[idx][:, idx]
+        br = br[..., idx, :][..., idx]
+        bi = bi[..., idx, :][..., idx]
     return br, bi
 
 
+def _lane_op(u, inner: int, width: int):
+    """(..., width, width) = kron(I, u, I_inner): ``u`` (..., K, K) applied
+    to the K axis of a row laid out (width / (K * inner), K, inner)."""
+    K = u.shape[-1]
+    e_m = jnp.eye(width // (K * inner), dtype=u.dtype)
+    e_i = jnp.eye(inner, dtype=u.dtype)
+    op = (e_m[:, None, None, :, None, None]
+          * u[..., None, :, None, None, :, None]
+          * e_i[None, None, :, None, None, :])
+    return op.reshape(u.shape[:-2] + (width, width))
+
+
+def _dense(ar, ai, br, bi, outer: int, K: int, inner: int, *,
+           use_kernel: bool, shared: bool):
+    """C[o] = U @ A[o] over the (L, outer, K, inner) view of flat (L, N)
+    planes; ``br``/``bi`` are U's planes, one (K, K) pair for every row
+    when ``shared``, else one per row ((L, K, K))."""
+    from ..kernels import gate_apply as ga
+    lanes, n = ar.shape
+    if K * inner <= _LANES and n >= _TILE:
+        # the gate's bits lie inside one 128-lane row: a lane-dense
+        # (rows, 128) @ (128, 128) product with the operand
+        # kron(I, U, I_inner) — no narrow minor axis, which a TPU pads
+        # to 128 lanes (groups under one (8, 128) tile keep W = K)
+        width = _LANES
+        br, bi = _lane_op(br, inner, width), _lane_op(bi, inner, width)
+    else:
+        width = K if inner == 1 else 0
+    if width:
+        tr, ti = br.swapaxes(-1, -2), bi.swapaxes(-1, -2)   # U -> U^T
+        a2r = ar.reshape(lanes, -1, width)
+        a2i = ai.reshape(lanes, -1, width)
+        # the Pallas GEMM keeps both (W, W) operands in VMEM: one MXU
+        # tile wide at most; wider operands go to XLA's tiled matmul
+        if use_kernel and shared and width <= _LANES:
+            cr, ci = ga.gemm_planes(a2r.reshape(-1, width),
+                                    a2i.reshape(-1, width), tr, ti)
+        elif use_kernel and width <= _LANES:
+            cr, ci = ga.gemm_planes_batch(a2r, a2i, tr, ti)
+        else:
+            cr = _mm(a2r, tr) - _mm(a2i, ti)
+            ci = _mm(a2r, ti) + _mm(a2i, tr)
+    else:
+        a3r = ar.reshape(lanes, outer, K, inner)
+        a3i = ai.reshape(lanes, outer, K, inner)
+        if use_kernel and shared and inner >= _LANES:
+            # wide inner axis: lanes stay dense, MXU-shaped kernel
+            cr, ci = ga.gemm_planes_mid(a3r.reshape(-1, K, inner),
+                                        a3i.reshape(-1, K, inner), br, bi)
+        else:
+            spec = "jk,loki->loji" if shared else "ljk,loki->loji"
+            cr = _einsum(spec, br, a3r) - _einsum(spec, bi, a3i)
+            ci = _einsum(spec, br, a3i) + _einsum(spec, bi, a3r)
+    return cr.reshape(lanes, -1), ci.reshape(lanes, -1)
+
+
+def _diag(ar, ai, dr, di, op: DiagOp, *, use_kernel: bool, shared: bool):
+    """Scale flat (L, N) planes by diagonal planes ``dr``/``di`` ((K,)
+    when ``shared``, else (L, K)) in the current layout."""
+    lanes, n = ar.shape
+    K = 1 << op.k
+    if use_kernel and shared and op.minor and K >= _LANES:
+        # full-lane diagonal: the VPU row kernel is worth the call
+        from ..kernels.gate_apply import diag_apply
+        cr, ci = diag_apply(ar.reshape(-1, K), ai.reshape(-1, K), dr, di)
+        return cr.reshape(lanes, -1), ci.reshape(lanes, -1)
+    # each amplitude's diagonal entry from its own index bits: one
+    # lane-dense pass, whatever the layout
+    i = lax.broadcasted_iota(jnp.int32, (n,), 0)
+    sel = sum(((i >> b) & 1) << j for j, b in enumerate(op.bits))
+    fr, fi = _lookup(dr, sel), _lookup(di, sel)
+    return ar * fr - ai * fi, ar * fi + ai * fr
+
+
+def _lookup(d, sel):
+    """``d[..., sel]`` for a short table ``d``, as a chain of selects: the
+    TPU compiler takes about ten times longer over the equivalent
+    gather."""
+    out = jnp.broadcast_to(d[..., :1], d.shape[:-1] + sel.shape)
+    for s in range(1, d.shape[-1]):
+        out = jnp.where(sel == s, d[..., s:s + 1], out)
+    return out
+
+
+def execute_schedule_batched(sched: StageSchedule, planes, mats, *,
+                             use_kernel: bool, shared: bool = False):
+    """Run a compiled schedule over an (L, 2, 2^nv) f32 plane stack.
+
+    Each of the L rows is an SV group's (re, im) planes.  When ``shared``
+    one set of operands applies to every row — a wave of groups of one
+    state: ``mats[i]`` is ``(2, K, K)`` stacked re/im of U for dense
+    gates, ``(2, K)`` for diagonal ones.  Otherwise every operand has a
+    leading row axis (``(L, 2, K, K)`` / ``(L, 2, K)``) and row ``l``'s
+    unitaries apply to row ``l`` — the lanes of a parameter sweep or
+    noise-trajectory batch.  Either way the whole stack traces into ONE
+    jitted call per stage.
+
+    The planes stay flat: every op reshapes to the view it needs, with
+    the gate's axes merged into as few, as large axes as its position
+    allows.  ``use_kernel`` selects the Pallas kernels over plain XLA
+    contractions.
+    """
+    nv = sched.nv
+    ar, ai = planes[:, 0], planes[:, 1]
+    for op in sched.ops:
+        if isinstance(op, TransposeOp):
+            ar, ai = _transpose(ar, op.perm), _transpose(ai, op.perm)
+        elif isinstance(op, (GemmOp, MidGemmOp)):
+            K = 1 << op.k
+            inner = op.inner if isinstance(op, MidGemmOp) else 1
+            br, bi = _operand(mats[op.idx], op.bmap)
+            ar, ai = _dense(ar, ai, br, bi, (1 << nv) // (K * inner), K,
+                            inner, use_kernel=use_kernel, shared=shared)
+        else:                                   # DiagOp
+            m = mats[op.idx]
+            ar, ai = _diag(ar, ai, m[..., 0, :], m[..., 1, :], op,
+                           use_kernel=use_kernel, shared=shared)
+    return _stack_planes(ar, ai)
+
+
+def _stack_planes(ar, ai):
+    """(L, 2, N) from (L, N) re and im.  The barrier keeps the stack out
+    of the producers' fusion: fused into it, the TPU compiler's fusion
+    emitter aborts on some stage programs (an unaligned
+    dynamic-update-slice check)."""
+    return jnp.stack(lax.optimization_barrier((ar, ai)), axis=1)
+
+
 def execute_schedule(sched: StageSchedule, planes, mats, *,
-                     use_kernel: bool, interpret: bool = True):
-    """Run a compiled schedule over a (2, 2^nv) f32 plane stack.
+                     use_kernel: bool):
+    """Run a compiled schedule over one (2, 2^nv) f32 plane stack.
 
     ``mats[i]`` is gate i's operand in plane form: ``(2, K, K)`` stacked
     re/im of U for dense gates (each op folds its own transpose into the
     contraction), ``(2, K)`` stacked re/im of the diagonal for diagonal
-    gates.  Traced under jit by the engine; ``use_kernel`` selects the
-    Pallas kernels over plain XLA contractions.
+    gates.  Traced under jit by the engine.
     """
-    nv = sched.nv
-    shape = (2,) * nv
-    ar = planes[0].reshape(shape)
-    ai = planes[1].reshape(shape)
-    for op in sched.ops:
-        if isinstance(op, TransposeOp):
-            ar = ar.transpose(op.perm)
-            ai = ai.transpose(op.perm)
-        elif isinstance(op, GemmOp):
-            K = 1 << op.k
-            br, bi = _op_mat(mats[op.idx], op.bmap)
-            br, bi = br.T, bi.T                              # U -> U^T
-            a2r, a2i = ar.reshape(-1, K), ai.reshape(-1, K)
-            if use_kernel:
-                from ..kernels.gate_apply import gemm_planes
-                cr, ci = gemm_planes(a2r, a2i, br, bi, interpret=interpret)
-            else:
-                cr = a2r @ br - a2i @ bi
-                ci = a2r @ bi + a2i @ br
-            ar, ai = cr.reshape(shape), ci.reshape(shape)
-        elif isinstance(op, MidGemmOp):
-            K = 1 << op.k
-            br, bi = _op_mat(mats[op.idx], op.bmap)
-            a3r = ar.reshape(op.outer, K, op.inner)
-            a3i = ai.reshape(op.outer, K, op.inner)
-            if use_kernel and op.inner >= 128:
-                # wide inner axis: lanes stay dense, MXU-shaped kernel
-                from ..kernels.gate_apply import gemm_planes_mid
-                cr, ci = gemm_planes_mid(a3r, a3i, br, bi,
-                                         interpret=interpret)
-            else:
-                # narrow inner would degenerate the kernel grid — let the
-                # compiler batch the contraction instead
-                e = lambda b, a: jnp.einsum("jk,oki->oji", b, a)
-                cr = e(br, a3r) - e(bi, a3i)
-                ci = e(br, a3i) + e(bi, a3r)
-            ar, ai = cr.reshape(shape), ci.reshape(shape)
-        else:                                   # DiagOp
-            dr, di = mats[op.idx][0], mats[op.idx][1]
-            K = 1 << op.k
-            if use_kernel and op.minor and K >= 128:
-                # full-lane diagonal: the VPU row kernel is worth the call;
-                # narrower diagonals fuse better as plain broadcasts
-                from ..kernels.gate_apply import diag_apply
-                cr, ci = diag_apply(ar.reshape(-1, K), ai.reshape(-1, K),
-                                    dr, di, interpret=interpret)
-                ar, ai = cr.reshape(shape), ci.reshape(shape)
-            elif op.block is not None:
-                # contiguous axes: reshape + clean-axis broadcast of the
-                # (bit-permuted) K-entry diagonal
-                p, dmap = op.block
-                if dmap is not None:
-                    sel = jnp.asarray(dmap)
-                    dr, di = dr[sel], di[sel]
-                if p == nv - op.k:
-                    a2r, a2i = ar.reshape(-1, K), ai.reshape(-1, K)
-                    dr, di = dr[None, :], di[None, :]
-                else:
-                    inner = 1 << (nv - p - op.k)
-                    a2r = ar.reshape(-1, K, inner)
-                    a2i = ai.reshape(-1, K, inner)
-                    dr, di = dr[None, :, None], di[None, :, None]
-                cr = a2r * dr - a2i * di
-                ci = a2r * di + a2i * dr
-                ar, ai = cr.reshape(shape), ci.reshape(shape)
-            else:
-                # scattered axes: general nv-axis broadcast
-                d2 = (2,) * op.k
-                dr = dr.reshape(d2).transpose(op.dperm).reshape(op.shape)
-                di = di.reshape(d2).transpose(op.dperm).reshape(op.shape)
-                ar, ai = ar * dr - ai * di, ar * di + ai * dr
-    return jnp.stack([ar.reshape(-1), ai.reshape(-1)])
-
-
-def _op_mat_batch(mat, bmap: tuple[int, ...] | None):
-    """(L, 2, K, K) stacked per-lane U planes -> (br, bi) of shape
-    (L, K, K), bit-permuted when needed."""
-    br, bi = mat[:, 0], mat[:, 1]
-    if bmap is not None:
-        idx = jnp.asarray(bmap)
-        br = br[:, idx][:, :, idx]
-        bi = bi[:, idx][:, :, idx]
-    return br, bi
-
-
-def execute_schedule_batched(sched: StageSchedule, planes, mats, *,
-                             use_kernel: bool, interpret: bool = True):
-    """Run a compiled schedule over an (L, 2, 2^nv) f32 plane stack.
-
-    The lane-batched sibling of :func:`execute_schedule`: every operand
-    and the plane stack carry one extra leading lane axis — ``mats[i]``
-    is ``(L, 2, K, K)`` dense / ``(L, 2, K)`` diagonal — and lane ``l``'s
-    unitaries apply to lane ``l``'s planes.  The whole L-lane batch
-    (parameter-sweep bindings or noise trajectories) traces into ONE
-    jitted call per stage, which is where the dispatch-bound speedup of
-    ``Simulator.run_batch`` comes from.
-    """
-    nv = sched.nv
-    lanes = planes.shape[0]
-    shape = (lanes,) + (2,) * nv
-    ar = planes[:, 0].reshape(shape)
-    ai = planes[:, 1].reshape(shape)
-    for op in sched.ops:
-        if isinstance(op, TransposeOp):
-            perm = (0,) + tuple(a + 1 for a in op.perm)
-            ar = ar.transpose(perm)
-            ai = ai.transpose(perm)
-        elif isinstance(op, GemmOp):
-            K = 1 << op.k
-            br, bi = _op_mat_batch(mats[op.idx], op.bmap)
-            br, bi = br.swapaxes(1, 2), bi.swapaxes(1, 2)     # U -> U^T
-            a2r, a2i = ar.reshape(lanes, -1, K), ai.reshape(lanes, -1, K)
-            if use_kernel:
-                from ..kernels.gate_apply import gemm_planes_batch
-                cr, ci = gemm_planes_batch(a2r, a2i, br, bi,
-                                           interpret=interpret)
-            else:
-                cr = a2r @ br - a2i @ bi                      # lane-batched
-                ci = a2r @ bi + a2i @ br
-            ar, ai = cr.reshape(shape), ci.reshape(shape)
-        elif isinstance(op, MidGemmOp):
-            K = 1 << op.k
-            br, bi = _op_mat_batch(mats[op.idx], op.bmap)
-            a3r = ar.reshape(lanes, op.outer, K, op.inner)
-            a3i = ai.reshape(lanes, op.outer, K, op.inner)
-            # the batched middle contraction stays an einsum: the lane
-            # axis already amortizes dispatch, and XLA batches it fine
-            def e(b, a):
-                return jnp.einsum("ljk,loki->loji", b, a)
-            cr = e(br, a3r) - e(bi, a3i)
-            ci = e(br, a3i) + e(bi, a3r)
-            ar, ai = cr.reshape(shape), ci.reshape(shape)
-        else:                                   # DiagOp
-            dr, di = mats[op.idx][:, 0], mats[op.idx][:, 1]   # (L, K)
-            K = 1 << op.k
-            if op.block is not None:
-                p, dmap = op.block
-                if dmap is not None:
-                    sel = jnp.asarray(dmap)
-                    dr, di = dr[:, sel], di[:, sel]
-                if p == nv - op.k:
-                    a2r = ar.reshape(lanes, -1, K)
-                    a2i = ai.reshape(lanes, -1, K)
-                    db_r, db_i = dr[:, None, :], di[:, None, :]
-                else:
-                    inner = 1 << (nv - p - op.k)
-                    a2r = ar.reshape(lanes, -1, K, inner)
-                    a2i = ai.reshape(lanes, -1, K, inner)
-                    db_r, db_i = dr[:, None, :, None], di[:, None, :, None]
-                cr = a2r * db_r - a2i * db_i
-                ci = a2r * db_i + a2i * db_r
-                ar, ai = cr.reshape(shape), ci.reshape(shape)
-            else:
-                d2 = (2,) * op.k
-                perm = (0,) + tuple(a + 1 for a in op.dperm)
-                dshape = (lanes,) + op.shape
-                dr = dr.reshape((lanes,) + d2).transpose(perm).reshape(dshape)
-                di = di.reshape((lanes,) + d2).transpose(perm).reshape(dshape)
-                ar, ai = ar * dr - ai * di, ar * di + ai * dr
-    return jnp.stack([ar.reshape(lanes, -1), ai.reshape(lanes, -1)], axis=1)
+    return execute_schedule_batched(sched, planes[None], mats,
+                                    use_kernel=use_kernel, shared=True)[0]

@@ -39,7 +39,6 @@ from ..compression.pwrel import PwRelParams
 from ..compression.store import BlockStore
 from ..errors import (BlockCorruptionError, MemoryPressureError,
                       ResumableError, StoreIOError)
-from ..kernels.ops import default_interpret
 from .circuit import Circuit
 from .engine import BMQSimEngine, EngineConfig, SimStats
 from .pipeline import make_backend
@@ -465,7 +464,7 @@ class Simulator:
             sim._backend = make_backend(
                 "host", store, PwRelParams(b_r=meta["b_r"]),
                 2 ** meta["local_bits"], compression=meta["compression"],
-                prescan=meta["prescan"], interpret=default_interpret())
+                prescan=meta["prescan"])
             sim.n_qubits = meta["n_qubits"]
             sim.local_bits = meta["local_bits"]
             sim._meta = meta

@@ -23,14 +23,13 @@ in :mod:`repro.distributed.sharding` (quarantined — see
 blocks*, not parameter pytrees.
 
 Everything here is deliberately explicit-placement (``jax.device_put``
-per shard) rather than GSPMD: the Pallas codec kernels run in interpret
-mode on CPU hosts and must see plain per-device arrays, and explicit
-shards keep the store-key partition — the thing checkpoints and the
-exchange ledger reason about — trivially auditable.
+per shard) rather than GSPMD: the Pallas codec kernels must see plain
+per-device arrays, and explicit shards keep the store-key partition —
+the thing checkpoints and the exchange ledger reason about — trivially
+auditable.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 from typing import Any, Sequence
 
@@ -62,10 +61,10 @@ def sim_devices(n_devices: int | None = None,
     """The device list one simulation mesh is built over.
 
     ``devices`` (default: ``jax.devices()``) is truncated to
-    ``n_devices`` when given; asking for more devices than are visible
-    clamps to the visible count with a ``RuntimeWarning`` (on a CPU host
-    pass ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — or
-    ``qsim --devices N``, which sets it — to create virtual devices).
+    ``n_devices`` when given; asking for more devices than are visible is
+    a ``ValueError`` (on a CPU host pass
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — or ``qsim
+    --devices N``, which sets it there — to create virtual devices).
     """
     devs = list(devices) if devices is not None else list(jax.devices())
     if not devs:
@@ -75,12 +74,11 @@ def sim_devices(n_devices: int | None = None,
     if n_devices < 1:
         raise ValueError(f"n_devices={n_devices} must be >= 1")
     if n_devices > len(devs):
-        warnings.warn(
-            f"requested {n_devices} devices but only {len(devs)} are "
-            f"visible; clamping (set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n_devices} for "
-            "virtual host devices)", RuntimeWarning, stacklevel=2)
-        return devs
+        raise ValueError(
+            f"requested {n_devices} devices but only {len(devs)} "
+            f"{devs[0].platform} device(s) are visible (a CPU host gets "
+            f"virtual ones from XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_devices})")
     return devs[:n_devices]
 
 
@@ -107,14 +105,8 @@ def make_lane_mesh(mesh_shape: tuple[int, ...] | int | None = None,
 
 
 def activate_mesh(mesh: Mesh):
-    """Context manager activating ``mesh``, across jax versions.
-
-    jax >= 0.6 spells it ``jax.set_mesh(mesh)``; on 0.4/0.5 the Mesh
-    object is itself the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def lane_spec() -> PartitionSpec:

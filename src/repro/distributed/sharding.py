@@ -29,14 +29,8 @@ __all__ = ["TP_AXIS", "activate_mesh", "dp_axes", "param_pspecs",
 
 
 def activate_mesh(mesh: Mesh):
-    """Context manager activating ``mesh``, across jax versions.
-
-    jax >= 0.6 spells it ``jax.set_mesh(mesh)``; on 0.4/0.5 the Mesh
-    object is itself the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def dp_axes(mesh: Mesh):

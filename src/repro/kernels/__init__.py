@@ -1,1 +1,2 @@
-"""Pallas TPU kernels (validated via interpret=True on CPU; see ops.py)."""
+"""Pallas TPU kernels: compiled on a TPU, interpreted on the CPU (see
+platform.py)."""

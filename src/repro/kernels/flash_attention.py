@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 __all__ = ["flash_attention", "DEFAULT_Q_TILE", "DEFAULT_K_TILE"]
 
 DEFAULT_Q_TILE = 256
@@ -75,8 +77,7 @@ def _flash_kernel(scale: float, k_tile: int, causal: bool,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     q_tile: int = DEFAULT_Q_TILE,
-                    k_tile: int = DEFAULT_K_TILE,
-                    interpret: bool = True) -> jax.Array:
+                    k_tile: int = DEFAULT_K_TILE) -> jax.Array:
     """q/k/v: (BH, S, hd) f32 -> (BH, S, hd) f32 (softmax(qk^T/sqrt)v)."""
     BH, S, hd = q.shape
     tq = min(q_tile, S)
@@ -87,7 +88,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         tk //= 2
     scale = hd ** -0.5
     grid = (BH, S // tq)
-    fn = pl.pallas_call(
+    fn = pallas_call(
         functools.partial(_flash_kernel, scale, tk, causal),
         grid=grid,
         in_specs=[
@@ -97,6 +98,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, tq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
-        interpret=interpret,
     )
     return fn(q, k, v)

@@ -34,12 +34,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+
+from .platform import pallas_call
 
 __all__ = ["gemm_planes", "gemm_planes_batch", "gemm_planes_mid",
            "diag_apply", "DEFAULT_ROW_TILE"]
 
 DEFAULT_ROW_TILE = 256
+
+#: f32 contractions at full f32 precision: the fidelity a simulation
+#: reports must not depend on the MXU's default bf16 passes
+_dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                         precision=lax.Precision.HIGHEST)
 
 
 def _gemm_kernel(ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref):
@@ -47,14 +55,12 @@ def _gemm_kernel(ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref):
     ai = ai_ref[...]
     br = br_ref[...]
     bi = bi_ref[...]
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
-    cr_ref[...] = dot(ar, br) - dot(ai, bi)
-    ci_ref[...] = dot(ar, bi) + dot(ai, br)
+    cr_ref[...] = _dot(ar, br) - _dot(ai, bi)
+    ci_ref[...] = _dot(ar, bi) + _dot(ai, br)
 
 
 def gemm_planes(ar: jax.Array, ai: jax.Array, br: jax.Array, bi: jax.Array,
-                *, row_tile: int = DEFAULT_ROW_TILE,
-                interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                *, row_tile: int = DEFAULT_ROW_TILE) -> tuple[jax.Array, jax.Array]:
     """(R, K) x (K, K) complex GEMM over separate re/im f32 planes."""
     R, K = ar.shape
     assert br.shape == (K, K) and bi.shape == (K, K) and ai.shape == (R, K)
@@ -66,13 +72,12 @@ def gemm_planes(ar: jax.Array, ai: jax.Array, br: jax.Array, bi: jax.Array,
     b_spec = pl.BlockSpec((K, K), lambda i: (0, 0))
     out_spec = pl.BlockSpec((tr, K), lambda i: (i, 0))
     out_shape = [jax.ShapeDtypeStruct((R, K), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    fn = pallas_call(
         _gemm_kernel,
         grid=grid,
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[out_spec, out_spec],
         out_shape=out_shape,
-        interpret=interpret,
     )
     return fn(ar, ai, br, bi)
 
@@ -82,15 +87,13 @@ def _gemm_batch_kernel(ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref):
     ai = ai_ref[0]
     br = br_ref[0]            # (K, K) = lane's own U^T
     bi = bi_ref[0]
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
-    cr_ref[0] = dot(ar, br) - dot(ai, bi)
-    ci_ref[0] = dot(ar, bi) + dot(ai, br)
+    cr_ref[0] = _dot(ar, br) - _dot(ai, bi)
+    ci_ref[0] = _dot(ar, bi) + _dot(ai, br)
 
 
 def gemm_planes_batch(ar: jax.Array, ai: jax.Array,
                       br: jax.Array, bi: jax.Array,
-                      *, row_tile: int = DEFAULT_ROW_TILE,
-                      interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                      *, row_tile: int = DEFAULT_ROW_TILE) -> tuple[jax.Array, jax.Array]:
     """(L, R, K) x (L, K, K) lane-batched complex GEMM on re/im planes.
 
     The batched-execution sibling of :func:`gemm_planes`: lane ``l`` of A
@@ -109,13 +112,12 @@ def gemm_planes_batch(ar: jax.Array, ai: jax.Array,
     a_spec = pl.BlockSpec((1, tr, K), lambda lane, i: (lane, i, 0))
     b_spec = pl.BlockSpec((1, K, K), lambda lane, i: (lane, 0, 0))
     out_shape = [jax.ShapeDtypeStruct((L, R, K), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    fn = pallas_call(
         _gemm_batch_kernel,
         grid=grid,
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[a_spec, a_spec],
         out_shape=out_shape,
-        interpret=interpret,
     )
     return fn(ar, ai, br, bi)
 
@@ -125,15 +127,13 @@ def _gemm_mid_kernel(ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref):
     ai = ai_ref[0]
     br = br_ref[...]          # (K, K) = U, broadcast to every program
     bi = bi_ref[...]
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
-    cr_ref[0] = dot(br, ar) - dot(bi, ai)
-    ci_ref[0] = dot(br, ai) + dot(bi, ar)
+    cr_ref[0] = _dot(br, ar) - _dot(bi, ai)
+    ci_ref[0] = _dot(br, ai) + _dot(bi, ar)
 
 
 def gemm_planes_mid(ar: jax.Array, ai: jax.Array,
                     br: jax.Array, bi: jax.Array,
-                    *, inner_tile: int = DEFAULT_ROW_TILE,
-                    interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                    *, inner_tile: int = DEFAULT_ROW_TILE) -> tuple[jax.Array, jax.Array]:
     """(O, K, I) complex batched left-GEMM C[o] = U @ A[o] on re/im planes.
 
     ``br``/``bi`` are U's planes (NOT transposed — the contraction is over
@@ -150,13 +150,12 @@ def gemm_planes_mid(ar: jax.Array, ai: jax.Array,
     a_spec = pl.BlockSpec((1, K, ti), lambda o, t: (o, 0, t))
     b_spec = pl.BlockSpec((K, K), lambda o, t: (0, 0))
     out_shape = [jax.ShapeDtypeStruct((O, K, I), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    fn = pallas_call(
         _gemm_mid_kernel,
         grid=grid,
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[a_spec, a_spec],
         out_shape=out_shape,
-        interpret=interpret,
     )
     return fn(ar, ai, br, bi)
 
@@ -171,8 +170,7 @@ def _diag_kernel(ar_ref, ai_ref, dr_ref, di_ref, cr_ref, ci_ref):
 
 
 def diag_apply(ar: jax.Array, ai: jax.Array, dr: jax.Array, di: jax.Array,
-               *, row_tile: int = DEFAULT_ROW_TILE,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               *, row_tile: int = DEFAULT_ROW_TILE) -> tuple[jax.Array, jax.Array]:
     """Elementwise complex multiply by a diagonal (1, K) — VPU path."""
     R, K = ar.shape
     tr = min(row_tile, R)
@@ -183,12 +181,11 @@ def diag_apply(ar: jax.Array, ai: jax.Array, dr: jax.Array, di: jax.Array,
     d_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
     out_spec = pl.BlockSpec((tr, K), lambda i: (i, 0))
     out_shape = [jax.ShapeDtypeStruct((R, K), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    fn = pallas_call(
         _diag_kernel,
         grid=grid,
         in_specs=[a_spec, a_spec, d_spec, d_spec],
         out_specs=[out_spec, out_spec],
         out_shape=out_shape,
-        interpret=interpret,
     )
     return fn(ar, ai, dr.reshape(1, K), di.reshape(1, K))

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 __all__ = ["kv_dequant_decode_attention", "KV_RANGE", "KV_STEP"]
 
 KV_RANGE = 16.0            # log2 units below the per-(token,head) max
@@ -82,8 +84,7 @@ def _kernel(k_tile: int, q_ref, ck_ref, sk_ref, lk_ref, cv_ref, sv_ref,
 
 def kv_dequant_decode_attention(q, codes_k, signs_k, scale_k,
                                 codes_v, signs_v, scale_v, pos, *,
-                                k_tile: int = 512,
-                                interpret: bool = True):
+                                k_tile: int = 512):
     """q (BG, rep, hd) f32; cache leaves (BG, T, ...) -> out (BG, rep, hd).
 
     ``pos``: scalar int32 — last valid cache index (causal mask j <= pos).
@@ -95,7 +96,7 @@ def kv_dequant_decode_attention(q, codes_k, signs_k, scale_k,
         tk //= 2
     grid = (BG,)
     full = lambda w, dt: pl.BlockSpec((1, T, w), lambda b: (b, 0, 0))  # noqa: E731
-    fn = pl.pallas_call(
+    fn = pallas_call(
         functools.partial(_kernel, tk),
         grid=grid,
         in_specs=[
@@ -106,7 +107,6 @@ def kv_dequant_decode_attention(q, codes_k, signs_k, scale_k,
         ],
         out_specs=pl.BlockSpec((1, rep, hd), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BG, rep, hd), jnp.float32),
-        interpret=interpret,
     )
     pos2d = jnp.asarray(pos, jnp.int32).reshape(1, 1)
     return fn(q, codes_k, signs_k, scale_k, codes_v, signs_v, scale_v, pos2d)

@@ -28,15 +28,20 @@ from ..core import (EngineConfig, Simulator, build_circuit,
 from ..core.faults import INJECTION_POINTS, inject_faults
 from ..core.planner import estimate_bytes_per_amp
 from ..errors import ResumableError
+from .cache import use_compile_cache
 
 
 def _ensure_host_devices(n):
-    """Expose ``n`` virtual CPU devices before the jax backend spins up.
+    """Expose ``n`` virtual CPU devices before the jax backend spins up —
+    only when the process is pinned to the CPU (``JAX_PLATFORMS=cpu``):
+    an accelerator run uses the devices it has.
 
     Must run before the first device query of the process; once the
-    backend is initialized the flag is inert (``sim_devices`` then clamps
-    the mesh to whatever is visible, with a warning).
+    backend is initialized the flag is inert (``sim_devices`` then
+    refuses a mesh larger than what is visible).
     """
+    if jax.config.jax_platforms != "cpu":
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -74,8 +79,9 @@ def main(argv=None):
                     help="run on a D-device mesh: lanes shard across "
                          "devices when batched, SV block groups shard "
                          "across devices otherwise (only encoded wire "
-                         "crosses device boundaries); on CPU, forces D "
-                         "virtual host devices")
+                         "crosses device boundaries); with "
+                         "JAX_PLATFORMS=cpu, forces D virtual host "
+                         "devices")
     ap.add_argument("--codec-backend", default="host",
                     choices=("host", "device"),
                     help="where the lossy codec runs; 'device' ships only "
@@ -144,6 +150,7 @@ def main(argv=None):
                     help="disable block checksums and the memory-"
                          "pressure monitor (benchmark baseline)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.devices is not None and args.devices < 1:
         ap.error("--devices needs a positive device count")

@@ -22,6 +22,7 @@ import argparse
 
 from ..core import EngineConfig, SimService, build_circuit, with_depolarizing
 from ..core.library import CIRCUIT_BUILDERS
+from .cache import use_compile_cache
 
 
 def parse_workload(spec: str) -> list[tuple[str, int]]:
@@ -81,6 +82,7 @@ def main(argv=None) -> int:
                     help="submit round-robin across structures instead of "
                          "spec order (more realistic mixed traffic)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     budget = int(args.memory_budget * 2 ** 20)
     workload = parse_workload(args.jobs)
