@@ -118,6 +118,7 @@ def phase_simulator(name: str, n: int, codec: str,
     """One circuit through ``Simulator`` with the Pallas kernels on,
     checked against the dense oracle.  ``gates`` cuts the circuit to a
     prefix of its gate list (same width, less depth)."""
+    from repro import spans
     from repro.core import Circuit, EngineConfig, Simulator, build_circuit
     qc = build_circuit(name, n)
     if gates is not None:
@@ -135,7 +136,8 @@ def phase_simulator(name: str, n: int, codec: str,
              stages=st.n_stages, local_bits=sim.local_bits,
              compile_s=compile_s, run_s=wall - compile_s,
              h2d_bytes=st.h2d_bytes, d2h_bytes=st.d2h_bytes,
-             stored_bytes_per_amp=st.bytes_per_amp_measured)
+             stored_bytes_per_amp=spans.recent(st.last_run)[-1].stored_bytes
+             / 2 ** n)
     m["ok"] = bool(_passed(m))
     return m
 

@@ -13,7 +13,7 @@ first run).  Energies and samples stream from the compressed store; the
 import argparse
 
 from repro import (EngineConfig, Simulator, maxcut_cost_fn, maxcut_edges,
-                   qaoa_template)
+                   qaoa_template, spans)
 
 
 def main():
@@ -64,9 +64,11 @@ def main():
               f"(standard {stats.standard_bytes/2**20:.1f} MiB, "
               f"{stats.memory_reduction:.1f}x reduction); "
               f"spills={stats.n_spills}")
-        print(f"phase times: decompress {stats.t_decompress:.2f}s "
-              f"compute {stats.t_compute:.2f}s fetch {stats.t_fetch:.2f}s "
-              f"compress {stats.t_compress:.2f}s total {stats.t_total:.2f}s")
+        per_stage = spans.per_stage_s(spans.recent(stats.last_run))
+        print(f"last run, span seconds a stage: "
+              + ", ".join(f"{k} {v:.4f}" for k, v in
+                          sorted(per_stage.items()))
+              + f"; all runs total {stats.t_total:.2f}s")
 
         # the last run's handle is live: sample the best-energy angles'
         # state straight from the compressed store (peak extra memory =

@@ -5,11 +5,11 @@ The threaded wave scheduler (core/pipeline.py) and the two-level store
 threads.  The convention: declare the guard on the field's ``__init__``
 assignment —
 
-    self.t_load = 0.0          # guarded-by: _t_lock
+    self.h2d_bytes = 0         # guarded-by: _count_lock
 
-— and from then on every ``self.t_load`` access (read or write) must
-sit lexically inside ``with self._t_lock:`` in the same class, or in a
-method annotated ``# holds-lock: _t_lock`` (callers own the lock).
+— and from then on every ``self.h2d_bytes`` access (read or write) must
+sit lexically inside ``with self._count_lock:`` in the same class, or in a
+method annotated ``# holds-lock: _count_lock`` (callers own the lock).
 
 Scope and limits (by design, to stay zero-false-positive):
 

@@ -121,15 +121,16 @@ def _encode_plane_dev(x: jax.Array, pad: int, step: float) -> PlaneWire:
 def _encode_group_jit(planes: jax.Array, n_blocks: int, step: float):
     bsz = planes.shape[1] // n_blocks
     _, pad = plane_geometry(bsz)
-    re = planes[0].reshape(n_blocks, bsz)
-    im = planes[1].reshape(n_blocks, bsz)
-    out = []
-    for i in range(n_blocks):
-        out.append((
-            _encode_plane_dev(re[i], pad, step),
-            _encode_plane_dev(im[i], pad, step),
-        ))
-    return tuple(out)
+    with jax.named_scope("codec.encode"):
+        re = planes[0].reshape(n_blocks, bsz)
+        im = planes[1].reshape(n_blocks, bsz)
+        out = []
+        for i in range(n_blocks):
+            out.append((
+                _encode_plane_dev(re[i], pad, step),
+                _encode_plane_dev(im[i], pad, step),
+            ))
+        return tuple(out)
 
 
 def encode_group_planes(planes: jax.Array, n_blocks: int,
@@ -269,13 +270,15 @@ def _decode_blocks_jit(codes, signs, l_max, n: int, step: float):
     (k, 2, n) f32."""
     k2 = codes.shape[0]
     rows, _ = plane_geometry(n)
-    planes = [
-        _qz.dequantize_tiles(
-            _pk.unpack_codes_tiles(_word_rows(codes[i], rows, _LANES // 2)),
-            _word_rows(signs[i], rows, _LANES // 32),
-            l_max[i], step).reshape(-1)[:n]
-        for i in range(k2)]
-    return jnp.stack(planes).reshape(k2 // 2, 2, n)
+    with jax.named_scope("codec.decode"):
+        planes = [
+            _qz.dequantize_tiles(
+                _pk.unpack_codes_tiles(
+                    _word_rows(codes[i], rows, _LANES // 2)),
+                _word_rows(signs[i], rows, _LANES // 32),
+                l_max[i], step).reshape(-1)[:n]
+            for i in range(k2)]
+        return jnp.stack(planes).reshape(k2 // 2, 2, n)
 
 
 @partial(jax.jit, static_argnames=())

@@ -29,7 +29,7 @@ import time
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field, replace
-from functools import lru_cache
+from functools import lru_cache, wraps
 from typing import NamedTuple
 
 import jax
@@ -39,6 +39,7 @@ import numpy as np
 from ..compression.pwrel import PwRelParams
 from ..compression.store import BlockStore
 from ..distributed.lanes import make_lane_mesh, make_lane_shards
+from ..spans import StageRecord, next_run, stage_record
 from .faults import fault_point
 from .circuit import Circuit, Gate
 from .dense_engine import apply_matrix
@@ -185,12 +186,18 @@ class SimStats:
     run only — a sweep must not grow it without bound); the scalar byte
     counters keep accumulating lifetime totals across runs.
 
-    ``bytes_per_amp_measured`` is the achieved stored compression after
-    the first encoded stage of the latest run — the run-time calibration
-    of the planner's ``predicted.bytes_per_amp`` estimate.
-
-    ``t_compute`` is dispatch + kernel time only; the blocking wait at the
-    d2h boundary is ``t_fetch`` (previously misattributed to compute).
+    Every stage of a run leaves a :class:`~repro.spans.StageRecord` in
+    the ``repro.spans`` ring; ``last_run`` is the latest run's number, so
+    ``repro.spans.recent(stats.last_run)`` are its records (the stored
+    compression after a stage is its ``stored_bytes`` over 2^n × lanes).
+    ``t_decompress``, ``t_compute``, ``t_fetch`` and ``t_compress`` sum
+    the records' ``pipeline.load``, ``pipeline.dispatch``,
+    ``pipeline.device_wait`` + ``pipeline.d2h_wait`` and
+    ``pipeline.store`` spans: pool worker seconds of fetch + inflate,
+    dispatch-thread seconds of h2d staging + stage program + encode
+    dispatch (never a device wait), the blocking wait for a result (the
+    device's work, then the d2h copy), and pool worker seconds of
+    deflate + put.
     ``n_transposes_naive`` / ``n_transposes_scheduled`` count full-group
     transposes (per group execution) under the per-gate scheme vs the
     compiled stage schedule — both are recorded whichever path ran.
@@ -238,7 +245,8 @@ class SimStats:
     #: device id -> SV-group rows (one per group and lane) its stage
     #: waves ran, lifetime — shows whether a mesh run used every device
     rows_per_device: dict = field(default_factory=dict)
-    bytes_per_amp_measured: float = 0.0
+    #: span-ring run number of the latest run (-1 before the first)
+    last_run: int = -1
     n_transposes_naive: int = 0
     n_transposes_scheduled: int = 0
     t_decompress: float = 0.0
@@ -247,7 +255,7 @@ class SimStats:
     t_compress: float = 0.0
     t_partition: float = 0.0
     t_total: float = 0.0
-    #: group x stage phase executions behind the t_* pipeline timings —
+    #: group x stage phase executions behind the t_* pipeline spans —
     #: the denominator for the planner's per-group calibration
     n_group_phases: int = 0
     # -- resilience counters (see repro.core.pressure / repro.errors) -----
@@ -329,6 +337,16 @@ def _apply_fused(amps: jax.Array, mats: tuple[jax.Array, ...],
     return amps
 
 
+def _gates_scope(fn):
+    """``fn`` with its ops under the ``stage.gates`` name scope; the
+    jitted program keeps ``fn``'s name (``jit_fn``)."""
+    @wraps(fn)
+    def scoped(planes, *mats):
+        with jax.named_scope("stage.gates"):
+            return fn(planes, *mats)
+    return scoped
+
+
 @lru_cache(maxsize=512)
 def _stage_fn(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
               use_kernel: bool, gate_schedule: bool):
@@ -355,7 +373,7 @@ def _stage_fn(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
             amps = planes_to_complex(planes)
             amps = _apply_fused(amps, mats, plan, nv)
             return complex_to_planes(amps)
-    return jax.jit(fn, donate_argnums=0)
+    return jax.jit(_gates_scope(fn), donate_argnums=0)
 
 
 def _stage_mats(vgates: list[FusedGate],
@@ -398,7 +416,7 @@ def _stage_fn_batch(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
             mats = [jnp.tile(m, (d,) + (1,) * (m.ndim - 1)) for m in mats]
         return execute_schedule_batched(sched, planes, mats,
                                         use_kernel=use_kernel)
-    return jax.jit(fn, donate_argnums=0)
+    return jax.jit(_gates_scope(fn), donate_argnums=0)
 
 
 @lru_cache(maxsize=256)
@@ -415,7 +433,7 @@ def _stage_fn_wave(plan: tuple[tuple[tuple[int, ...], bool], ...], nv: int,
     def fn(planes, *mats):
         return execute_schedule_batched(sched, planes, mats,
                                         use_kernel=use_kernel, shared=True)
-    return jax.jit(fn, donate_argnums=0)
+    return jax.jit(_gates_scope(fn), donate_argnums=0)
 
 
 def _stage_mats_batch(lane_vgates, plan) -> list[jax.Array]:
@@ -748,31 +766,48 @@ class BMQSimEngine:
     def n_blocks(self) -> int:
         return 2 ** (self.n - self.b)
 
-    def _init_state(self) -> None:
-        self._init_lanes(0, 1)
+    def _init_state(self, run: int | None = None) -> None:
+        self._init_lanes(0, 1, run)
 
-    def _init_lanes(self, lane_base: int, lanes: int) -> None:
+    def _init_lanes(self, lane_base: int, lanes: int,
+                    run: int | None = None) -> None:
         """|0..0> in every lane of ``[lane_base, lane_base + lanes)``:
         the §4.2 trick generalizes — the one-hot first block and the zero
-        block are each encoded once and aliased across blocks AND lanes."""
+        block are each encoded once and aliased across blocks AND lanes.
+        Timed as the ``run.init`` record (stage -1) of run ``run``."""
         bsz = 2 ** self.b
         n_blocks = self.n_blocks
         base_key = lane_base * n_blocks
-        first = np.zeros(bsz, dtype=np.complex64)
-        first[0] = 1.0
-        self.backend.encode_host_block(base_key, first)
-        if n_blocks > 1:
-            self.backend.encode_host_block(base_key + 1,
-                                           np.zeros(bsz, np.complex64))
-        for lane in range(lanes):
-            off = (lane_base + lane) * n_blocks
-            for blk in range(n_blocks):
-                key = off + blk
-                if key == base_key or (n_blocks > 1 and key == base_key + 1):
-                    continue
-                self.store.put_alias(key,
-                                     base_key if blk == 0 else base_key + 1)
-        self.stats.n_block_compressions += min(n_blocks, 2)
+        if run is None:
+            run = next_run()
+        with stage_record("run.init", run, -1, lanes) as rec:
+            first = np.zeros(bsz, dtype=np.complex64)
+            first[0] = 1.0
+            self.backend.encode_host_block(base_key, first, rec)
+            if n_blocks > 1:
+                self.backend.encode_host_block(
+                    base_key + 1, np.zeros(bsz, np.complex64), rec)
+            for lane in range(lanes):
+                off = (lane_base + lane) * n_blocks
+                for blk in range(n_blocks):
+                    key = off + blk
+                    if key == base_key or (n_blocks > 1
+                                           and key == base_key + 1):
+                        continue
+                    self.store.put_alias(
+                        key, base_key if blk == 0 else base_key + 1)
+            rec.blocks_stored = min(n_blocks, 2)
+            rec.stored_bytes = self.store.total_bytes
+        self.stats.n_block_compressions += rec.blocks_stored
+
+    def _add_spans(self, rec: StageRecord) -> None:
+        """Sum a finished stage's phase spans into the run timings."""
+        sp = rec.span_s
+        self.stats.t_decompress += sp.get("pipeline.load", 0.0)
+        self.stats.t_compute += sp.get("pipeline.dispatch", 0.0)
+        self.stats.t_fetch += (sp.get("pipeline.device_wait", 0.0)
+                               + sp.get("pipeline.d2h_wait", 0.0))
+        self.stats.t_compress += sp.get("pipeline.store", 0.0)
 
     def _make_monitor(self, lanes: int = 1) -> PressureMonitor | None:
         """Arm the degradation ladder for one run (None when disabled)."""
@@ -844,6 +879,8 @@ class BMQSimEngine:
         """
         t_start = time.perf_counter()
         bound = self._bind_stages(params)
+        run = next_run()
+        self.stats.last_run = run
         self.stats.n_runs += 1
         self.stats.n_lanes = 1
         # per-run, not lifetime: a parameter sweep must not grow this
@@ -852,16 +889,15 @@ class BMQSimEngine:
         self.stats.per_stage_exchange_bytes = []
         if start_stage == 0:
             self._clear_lanes(1)
-            self._init_state()
+            self._init_state(run)
         pipe = StagePipeline(self.backend, depth=self.cfg.pipeline_depth,
-                             devices=self._devices)
+                             devices=self._devices, run=run)
         monitor = self._make_monitor()
         # snapshot the backend's lifetime counters so repeated run() calls
         # on one engine accumulate deltas, not running totals
         back = self.backend
         h2d0, d2h0 = back.h2d_bytes, back.d2h_bytes
         dec0, com0 = back.n_decompressions, back.n_compressions
-        first_done = False
         # block sharding (D > 1): groups follow the plan's device_slot
         # round-robin; `owners` tracks each block's slot so stage
         # boundaries account exactly the blocks that change hands
@@ -887,7 +923,6 @@ class BMQSimEngine:
                     bs.sched.n_transposes_naive * bs.layout.n_groups
                 self.stats.n_transposes_scheduled += \
                     bs.sched.n_transposes * bs.layout.n_groups
-                sh2d, sd2h = back.h2d_bytes, back.d2h_bytes
                 gids = bs.layout.group_block_ids()
                 group_devices = None
                 if D > 1:
@@ -898,28 +933,18 @@ class BMQSimEngine:
                     group_devices = [self._devices[int(s)] for s in slots]
                 else:
                     self.stats.per_stage_exchange_bytes.append(0)
-                pipe.run_stage(gids, bs.fn, bs.mats,
-                               wave_fn=bs.wave_fn,
-                               group_devices=group_devices)
+                rec = pipe.run_stage(gids, bs.fn, bs.mats,
+                                     wave_fn=bs.wave_fn,
+                                     group_devices=group_devices, stage=idx)
                 self.stats.per_stage_boundary_bytes.append(
-                    (back.h2d_bytes - sh2d, back.d2h_bytes - sd2h))
-                if not first_done:
-                    # calibrate the planner's compression-ratio estimate
-                    # against the first encoded stage (§4.4: variable
-                    # ratios are only known once real data flows)
-                    first_done = True
-                    self.stats.bytes_per_amp_measured = \
-                        self.store.total_bytes / 2 ** self.n
+                    (rec.h2d_bytes, rec.d2h_bytes))
+                self._add_spans(rec)
                 if on_stage_done is not None:
                     on_stage_done(idx)
                 if monitor is not None:
                     # after on_stage_done: a periodic checkpoint for this
                     # stage lands on disk before an abort can reference it
                     monitor.check(self.store, pipe, self.stats, idx + 1)
-        self.stats.t_decompress += pipe.t_load
-        self.stats.t_compute += pipe.t_compute
-        self.stats.t_fetch += pipe.t_fetch
-        self.stats.t_compress += pipe.t_store
         self.stats.n_group_phases += pipe.n_group_phases
         for dev, rows in pipe.rows_per_device.items():
             self.stats.rows_per_device[dev] = \
@@ -977,6 +1002,8 @@ class BMQSimEngine:
                 f"({self.cfg.memory_budget_bytes} B); executing "
                 f"{-(-lanes // chunk)} chunked sub-batches of <= {chunk}",
                 RuntimeWarning, stacklevel=2)
+        run = next_run()
+        self.stats.last_run = run
         self.stats.n_runs += 1
         self.stats.n_lanes = lanes
         self.stats.n_batch_chunks = -(-lanes // chunk)
@@ -990,17 +1017,18 @@ class BMQSimEngine:
         self._stored_lanes = lanes
         monitor = self._make_monitor(lanes)
         for base in range(0, lanes, chunk):
-            self._run_lane_chunk(bindings[base:base + chunk], base, monitor)
+            self._run_lane_chunk(bindings[base:base + chunk], base, run,
+                                 monitor)
         self.stats.t_total += time.perf_counter() - t_start
         self._snap_store_stats()
 
-    def _run_lane_chunk(self, bindings: tuple, lane_base: int,
+    def _run_lane_chunk(self, bindings: tuple, lane_base: int, run: int,
                         monitor: PressureMonitor | None = None) -> None:
-        """One feasible sub-batch: bind, init its lanes, walk the plan
-        with lane-batched pipeline stages."""
+        """One feasible sub-batch of run ``run``: bind, init its lanes,
+        walk the plan with lane-batched pipeline stages."""
         bound = self._bind_stages_batch(bindings)
         lanes = len(bindings)
-        self._init_lanes(lane_base, lanes)
+        self._init_lanes(lane_base, lanes, run)
         if monitor is not None:
             # bpa denominator: lanes materialized so far (finished
             # chunks' final states stay resident in the store)
@@ -1018,11 +1046,10 @@ class BMQSimEngine:
             if len(shards) == 1:
                 shards = None
         pipe = StagePipeline(self.backend, depth=self.cfg.pipeline_depth,
-                             devices=self._devices)
+                             devices=self._devices, run=run)
         back = self.backend
         h2d0, d2h0 = back.h2d_bytes, back.d2h_bytes
         dec0, com0 = back.n_decompressions, back.n_compressions
-        first_done = False
         with pipe:
             for stage_no, bs in enumerate(bound):
                 if not bs.plan:
@@ -1039,26 +1066,17 @@ class BMQSimEngine:
                     bs.sched.n_transposes_naive * bs.layout.n_groups * lanes
                 self.stats.n_transposes_scheduled += \
                     bs.sched.n_transposes * bs.layout.n_groups
-                sh2d, sd2h = back.h2d_bytes, back.d2h_bytes
-                pipe.run_stage(bs.layout.group_block_ids(), bs.fn, bs.mats,
-                               lane_offsets=offsets, wave_fn=bs.wave_fn,
-                               lane_shards=shards)
+                rec = pipe.run_stage(bs.layout.group_block_ids(), bs.fn,
+                                     bs.mats, lane_offsets=offsets,
+                                     wave_fn=bs.wave_fn, lane_shards=shards,
+                                     stage=stage_no)
                 self.stats.per_stage_boundary_bytes.append(
-                    (back.h2d_bytes - sh2d, back.d2h_bytes - sd2h))
+                    (rec.h2d_bytes, rec.d2h_bytes))
                 self.stats.per_stage_exchange_bytes.append(0)
-                if not first_done and lane_base == 0:
-                    # calibrate on the first chunk only: later chunks'
-                    # store totals include finished lanes' final states
-                    first_done = True
-                    self.stats.bytes_per_amp_measured = \
-                        self.store.total_bytes / (2 ** self.n * lanes)
+                self._add_spans(rec)
                 if monitor is not None:
                     monitor.check(self.store, pipe, self.stats,
                                   stage_no + 1)
-        self.stats.t_decompress += pipe.t_load
-        self.stats.t_compute += pipe.t_compute
-        self.stats.t_fetch += pipe.t_fetch
-        self.stats.t_compress += pipe.t_store
         self.stats.n_group_phases += pipe.n_group_phases
         for dev, rows in pipe.rows_per_device.items():
             self.stats.rows_per_device[dev] = \

@@ -59,7 +59,6 @@ from __future__ import annotations
 import os
 import queue
 import threading
-import time
 import warnings
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -76,6 +75,7 @@ from ..compression.device_codec import (decode_blocks_planes,
 from ..compression.pwrel import PwRelParams
 from ..compression.store import BlockStore
 from ..errors import BlockCorruptionError, StoreIOError
+from ..spans import StageRecord, next_run, span, stage_record
 from .faults import fault_point
 
 __all__ = ["CodecBackend", "HostCodecBackend", "DeviceCodecBackend",
@@ -127,17 +127,26 @@ class CodecBackend:
       never blocks.
     * ``await_result``      — ticket -> host result object; this is the
       ONLY blocking device wait in the pipeline, so the scheduler can park
-      it in the in-flight window while later waves dispatch.
+      it in the in-flight window while later waves dispatch.  The
+      pipeline blocks on the ticket's arrays first
+      (``jax.block_until_ready``), so the hook times the d2h copy alone.
 
     ``fetch_result`` (dispatch + await back to back) remains as the
     convenience form for sequential callers.
 
+    The host halves take the :class:`~repro.spans.StageRecord` of the
+    stage they run for (``None`` outside a stage) and time their store
+    and codec work as its ``store.get`` / ``codec.inflate`` and
+    ``codec.deflate`` / ``store.put`` spans.
+
     Byte counters ``h2d_bytes`` / ``d2h_bytes`` accumulate the size of
     every array that crosses the host↔device boundary — the quantity the
-    device-resident codec exists to shrink.  Phase hooks run concurrently
-    on worker threads, so ALL counter updates are read-modify-write under
-    ``_count_lock`` — use :meth:`add_bytes` / :meth:`add_counts`, never a
-    bare ``+=``.
+    device-resident codec exists to shrink; ``n_raw_compressions`` counts
+    the stored blocks that took the codec's RAW escape.  Phase hooks run
+    concurrently on worker threads, so ALL counter updates are
+    read-modify-write under ``_count_lock`` — use :meth:`add_bytes` /
+    :meth:`add_counts`, never a bare ``+=``; read them with
+    :meth:`counts`.
 
     Args:
         store: the two-level block store.
@@ -164,13 +173,22 @@ class CodecBackend:
         self.d2h_bytes = 0                     # guarded-by: _count_lock
         self.n_decompressions = 0              # guarded-by: _count_lock
         self.n_compressions = 0                # guarded-by: _count_lock
+        self.n_raw_compressions = 0            # guarded-by: _count_lock
         self._count_lock = threading.Lock()
 
-    def add_counts(self, decompressions: int = 0,
-                   compressions: int = 0) -> None:
+    def add_counts(self, decompressions: int = 0, compressions: int = 0,
+                   raw: int = 0) -> None:
         with self._count_lock:
             self.n_decompressions += decompressions
             self.n_compressions += compressions
+            self.n_raw_compressions += raw
+
+    def counts(self) -> tuple[int, int, int, int, int]:
+        """``(decompressions, compressions, raw compressions, h2d bytes,
+        d2h bytes)`` so far, read together under the lock."""
+        with self._count_lock:
+            return (self.n_decompressions, self.n_compressions,
+                    self.n_raw_compressions, self.h2d_bytes, self.d2h_bytes)
 
     def add_bytes(self, h2d: int = 0, d2h: int = 0) -> None:
         """Locked accumulation of the boundary byte ledger (hooks may run
@@ -180,25 +198,36 @@ class CodecBackend:
             self.d2h_bytes += d2h
 
     # -- host block codec (also used for init/collect outside the pipeline) --
-    def encode_host_block(self, key: int, amps: np.ndarray) -> None:
+    def encode_host_block(self, key: int, amps: np.ndarray,
+                          rec: StageRecord | None = None) -> None:
         """Compress one np block on the host and store it under ``key``."""
         fault_point("codec.encode")
         if not self.compression:
-            self.store.put(key, np.asarray(amps, np.complex64).tobytes())
-        else:
-            self.store.put_block(
-                key, encode_block_host(amps, self.params,
-                                       prescan=self.prescan))
+            with span("store.put", rec):
+                self.store.put(key, np.asarray(amps, np.complex64).tobytes())
+            return
+        with span("codec.deflate", rec):
+            seg = encode_block_host(amps, self.params, prescan=self.prescan)
+        with span("store.put", rec):
+            self.store.put_block(key, seg)
+        if seg.is_raw:
+            self.add_counts(raw=1)
 
-    def decode_host_block(self, key: int) -> np.ndarray:
+    def decode_host_block(self, key: int,
+                          rec: StageRecord | None = None) -> np.ndarray:
         """Fetch the block under ``key`` and decompress it on the host."""
         fault_point("codec.decode")
         if not self.compression:
-            return np.frombuffer(self.store.get(key), dtype=np.complex64)
-        return decode_block_host(self.store.get_block(key), self.params)
+            with span("store.get", rec):
+                return np.frombuffer(self.store.get(key), dtype=np.complex64)
+        with span("store.get", rec):
+            seg = self.store.get_block(key)
+        with span("codec.inflate", rec):
+            return decode_block_host(seg, self.params)
 
     # -- phase hooks ---------------------------------------------------------
-    def fetch_group(self, block_ids: np.ndarray):
+    def fetch_group(self, block_ids: np.ndarray,
+                    rec: StageRecord | None = None):
         """Worker thread: store -> host staging object for one group."""
         raise NotImplementedError
 
@@ -222,7 +251,8 @@ class CodecBackend:
         """Dispatch + await back to back (sequential convenience form)."""
         return self.await_result(self.dispatch_result(planes_dev, n_blocks))
 
-    def store_group(self, block_ids: np.ndarray, result) -> None:
+    def store_group(self, block_ids: np.ndarray, result,
+                    rec: StageRecord | None = None) -> None:
         """Worker thread: host result object -> store."""
         raise NotImplementedError
 
@@ -236,9 +266,10 @@ class CodecBackend:
     # single-row hooks; backends override where one stacked transfer /
     # one kernel dispatch can cover the batch.
 
-    def fetch_group_batch(self, key_rows: np.ndarray):
+    def fetch_group_batch(self, key_rows: np.ndarray,
+                          rec: StageRecord | None = None):
         """Worker thread: store -> host staging for all rows."""
-        return [self.fetch_group(row) for row in key_rows]
+        return [self.fetch_group(row, rec) for row in key_rows]
 
     def stage_to_device_batch(self, staged, device) -> jax.Array:
         """Dispatch thread: host staging -> (R, 2, 2^(b+m)) f32 plane
@@ -261,10 +292,11 @@ class CodecBackend:
         return self.await_result_batch(
             self.dispatch_result_batch(planes_dev, n_blocks))
 
-    def store_group_batch(self, key_rows: np.ndarray, results) -> None:
+    def store_group_batch(self, key_rows: np.ndarray, results,
+                          rec: StageRecord | None = None) -> None:
         """Worker thread: per-row host results -> store."""
         for row, res in zip(key_rows, results):
-            self.store_group(row, res)
+            self.store_group(row, res, rec)
 
 
 class HostCodecBackend(CodecBackend):
@@ -277,13 +309,13 @@ class HostCodecBackend(CodecBackend):
 
     name = "host"
 
-    def fetch_group(self, block_ids):
+    def fetch_group(self, block_ids, rec=None):
         # decode straight into one preallocated flat group array — no
         # per-group np.concatenate copy
         flat = np.empty(len(block_ids) * self.bsz, dtype=np.complex64)
         for i, bid in enumerate(block_ids):
             flat[i * self.bsz:(i + 1) * self.bsz] = \
-                self.decode_host_block(int(bid))
+                self.decode_host_block(int(bid), rec)
         self.add_counts(decompressions=len(block_ids))
         return flat
 
@@ -301,20 +333,20 @@ class HostCodecBackend(CodecBackend):
         self.add_bytes(d2h=out.nbytes)
         return out
 
-    def store_group(self, block_ids, result):
+    def store_group(self, block_ids, result, rec=None):
         blocks = np.asarray(result).reshape(len(block_ids), self.bsz)
         for i, bid in enumerate(block_ids):
-            self.encode_host_block(int(bid), blocks[i])
+            self.encode_host_block(int(bid), blocks[i], rec)
         self.add_counts(compressions=len(block_ids))
 
     # -- row-batched overrides: one stacked boundary crossing per wave --
-    def fetch_group_batch(self, key_rows):
+    def fetch_group_batch(self, key_rows, rec=None):
         rows, n_blocks = key_rows.shape
         flat = np.empty((rows, n_blocks * self.bsz), dtype=np.complex64)
         for r, row in enumerate(key_rows):
             for i, bid in enumerate(row):
                 flat[r, i * self.bsz:(i + 1) * self.bsz] = \
-                    self.decode_host_block(int(bid))
+                    self.decode_host_block(int(bid), rec)
         self.add_counts(decompressions=key_rows.size)
         return flat
 
@@ -350,16 +382,18 @@ class DeviceCodecBackend(CodecBackend):
         assert compression, "device codec backend requires compression=True"
         super().__init__(store, params, bsz, compression, prescan)
 
-    def fetch_group(self, block_ids):
+    def fetch_group(self, block_ids, rec=None):
         staged = []
         for bid in block_ids:
             fault_point("codec.decode")
-            seg = self.store.get_block(int(bid))
+            with span("store.get", rec):
+                seg = self.store.get_block(int(bid))
             if seg.is_raw:
                 staged.append(("raw", np.frombuffer(
                     seg.raw, dtype=np.complex64, count=seg.n_amps)))
             else:
-                staged.append(("wire", segments_to_wire(seg)))
+                with span("codec.inflate", rec):
+                    staged.append(("wire", segments_to_wire(seg)))
         self.add_counts(decompressions=len(staged))
         return staged
 
@@ -401,14 +435,17 @@ class DeviceCodecBackend(CodecBackend):
         self.add_bytes(d2h=moved)
         return wire
 
-    def store_group(self, block_ids, result):
+    def store_group(self, block_ids, result, rec=None):
+        raw = 0
         for pair, bid in zip(result, block_ids):
             fault_point("codec.encode")
-            self.store.put_block(
-                int(bid), wire_to_segments(pair, self.bsz,
-                                           prescan=self.prescan,
-                                           params=self.params))
-        self.add_counts(compressions=len(block_ids))
+            with span("codec.deflate", rec):
+                seg = wire_to_segments(pair, self.bsz, prescan=self.prescan,
+                                       params=self.params)
+            with span("store.put", rec):
+                self.store.put_block(int(bid), seg)
+            raw += seg.is_raw
+        self.add_counts(compressions=len(block_ids), raw=raw)
 
     # -- row-batched overrides: every row's wire shares one codec
     # dispatch (the per-call decode/encode launch is the dominant cost on
@@ -498,25 +535,25 @@ class StagePipeline:
     sequential reference schedule.
 
     Use as a context manager (owns the worker pools); call
-    :meth:`run_stage` once per partition stage, then read the counters off
-    ``backend`` and the ``t_*`` attributes:
-
-    ``t_load``    host fetch/decode time (worker threads)
-    ``t_compute`` H2D staging + compute + encode *dispatch* time — async
-                  dispatch only, never a device wait
-    ``t_fetch``   blocking ``await_result`` wait at the D2H boundary
-    ``t_store``   host encode/store time (worker threads)
+    :meth:`run_stage` once per partition stage.  Each call returns the
+    stage's :class:`~repro.spans.StageRecord` (also kept in the
+    ``repro.spans`` ring under run number ``run``): its spans time the
+    phases on every thread (see :mod:`repro.spans`), its counters the
+    blocks and bytes the stage moved.
 
     ``n_group_phases`` counts group×stage phase executions — the
-    denominator that turns the ``t_*`` sums into the per-group
+    denominator that turns the summed phase spans into the per-group
     :class:`~repro.core.planner.PipelineCalibration` the planner's
     depth model consumes.
     """
 
     def __init__(self, backend: CodecBackend, depth: int = 2,
                  devices: list | None = None,
-                 fetch_workers: int | None = None):
+                 fetch_workers: int | None = None,
+                 run: int | None = None):
         self.backend = backend
+        #: the run number the stage records carry (a new one if None)
+        self.run = next_run() if run is None else run
         self.depth = max(1, depth)
         self.devices = devices or [jax.devices()[0]]
         # fetch pool width.  None = adaptive: one worker per spare core,
@@ -531,17 +568,9 @@ class StagePipeline:
         #: not the module constant — so the pressure ladder can shrink it
         #: to 1 between stages (rung 1) without rebuilding the pools.
         self.inflight_window = _INFLIGHT_WINDOW
-        # t_load/t_store accumulate inside concurrent worker threads and
-        # may only be touched under _t_lock (lock-discipline checker);
-        # t_compute/t_fetch belong to the dispatch thread alone
-        self.t_load = 0.0                      # guarded-by: _t_lock
-        self.t_compute = 0.0     # h2d staging + kernel dispatch (non-blocking)
-        self.t_fetch = 0.0       # blocking result wait at the d2h boundary
-        self.t_store = 0.0                     # guarded-by: _t_lock
         self.n_group_phases = 0
         #: device id -> group rows its waves ran (lane rows count apiece)
         self.rows_per_device: dict[int, int] = {}
-        self._t_lock = threading.Lock()
         self._dec_pool: ThreadPoolExecutor | None = None
         self._com_pool: ThreadPoolExecutor | None = None
         self._entered = False
@@ -572,7 +601,7 @@ class StagePipeline:
         self._dec_pool = self._com_pool = None
         self._entered = False
 
-    # -- timed phase wrappers (run inside worker threads) ---------------------
+    # -- phase wrappers (the host halves run inside worker threads) -----------
     @staticmethod
     def _key_span(keys) -> str:
         flat = np.asarray(keys).reshape(-1)
@@ -581,36 +610,46 @@ class StagePipeline:
         return (f"keys [{int(flat.min())}..{int(flat.max())}] "
                 f"({flat.size} blocks)")
 
-    def _load(self, fetch, keys):
-        t0 = time.perf_counter()
-        try:
-            fault_point("pipeline.fetch")
-            staged = fetch(keys)
-        except (StoreIOError, BlockCorruptionError):
-            raise                   # already typed with key/blob context
-        except OSError as e:
-            # a raw OSError escaping a fetch worker carries no context —
-            # name the wave so the failure is attributable
-            raise StoreIOError("pipeline fetch",
-                               detail=self._key_span(keys)) from e
-        dt = time.perf_counter() - t0
-        with self._t_lock:
-            self.t_load += dt
-        return staged
+    def _load(self, fetch, keys, rec):
+        with span("pipeline.load", rec):
+            try:
+                fault_point("pipeline.fetch")
+                return fetch(keys, rec)
+            except (StoreIOError, BlockCorruptionError):
+                raise               # already typed with key/blob context
+            except OSError as e:
+                # a raw OSError escaping a fetch worker carries no context
+                # — name the wave so the failure is attributable
+                raise StoreIOError("pipeline fetch",
+                                   detail=self._key_span(keys)) from e
 
-    def _store(self, store, keys, result):
-        t0 = time.perf_counter()
-        try:
-            fault_point("pipeline.store")
-            store(keys, result)
-        except (StoreIOError, BlockCorruptionError):
-            raise
-        except OSError as e:
-            raise StoreIOError("pipeline store",
-                               detail=self._key_span(keys)) from e
-        dt = time.perf_counter() - t0
-        with self._t_lock:
-            self.t_store += dt
+    def _store(self, store, keys, result, rec):
+        with span("pipeline.store", rec):
+            try:
+                fault_point("pipeline.store")
+                store(keys, result, rec)
+            except (StoreIOError, BlockCorruptionError):
+                raise
+            except OSError as e:
+                raise StoreIOError("pipeline store",
+                                   detail=self._key_span(keys)) from e
+
+    def _dispatch(self, wave_fn, staged, dev, imats, n_blocks, rec):
+        """Dispatch thread: h2d staging, the stage program and the encode
+        dispatch of one wave (async; never a device wait)."""
+        back = self.backend
+        with span("pipeline.dispatch", rec):
+            planes = back.stage_to_device_batch(staged, dev)
+            out = wave_fn(planes, *imats)
+            return back.dispatch_result_batch(out, n_blocks)
+
+    def _await(self, await_, ticket, rec):
+        """Dispatch thread: the blocking wait for a wave's result, in two
+        spans: until the device has computed it, then its d2h copy."""
+        with span("pipeline.device_wait", rec):
+            jax.block_until_ready(ticket)
+        with span("pipeline.d2h_wait", rec):
+            return await_(ticket)
 
     def _device_for(self, w: int):
         return self.devices[w % len(self.devices)]
@@ -618,10 +657,11 @@ class StagePipeline:
     def run_stage(self, block_ids: np.ndarray, fn, mats,
                   lane_offsets: np.ndarray | None = None,
                   wave_fn=None, lane_shards=None,
-                  group_devices=None) -> None:
+                  group_devices=None, stage: int = 0) -> StageRecord:
         """Run one stage: ``block_ids`` is the (n_groups, 2^m) layout table,
         ``fn`` the jitted single-group update function, ``mats`` its
-        operands.
+        operands, ``stage`` the stage's index in the plan.  Returns the
+        stage's record.
 
         ``wave_fn`` is the row-batched form of the stage update ((R, 2,
         2^(b+m)) planes -> same; operands broadcast/tiled in-trace) — it
@@ -655,22 +695,36 @@ class StagePipeline:
         assert self._entered, "use StagePipeline as a context manager"
         n_groups, n_blocks = block_ids.shape
         self.n_group_phases += n_groups
-        if wave_fn is None:
-            # legacy per-gate path: no batched form to shard a wave with,
-            # but _run_sequential_single already places group g on
-            # devices[g % D] — the same round-robin the plan's
-            # device_slot records
-            self._run_sequential_single(block_ids, fn, mats, lane_offsets)
-            return
-        items = self._wave_items(block_ids, mats, lane_offsets,
-                                 lane_shards, group_devices)
-        for keys, dev, _ in items:
-            self.rows_per_device[dev.id] = \
-                self.rows_per_device.get(dev.id, 0) + len(keys)
-        if self._dec_pool is None:
-            self._run_waves(items, wave_fn, n_blocks)
-            return
-        self._run_overlapped(items, wave_fn, n_blocks)
+        back = self.backend
+        lanes = 1 if lane_offsets is None else len(lane_offsets)
+        before = back.counts()
+        with stage_record("stage", self.run, stage, lanes) as rec:
+            rec.groups = n_groups
+            if wave_fn is None:
+                # legacy per-gate path: no batched form to shard a wave
+                # with, but _run_sequential_single already places group g
+                # on devices[g % D] — the same round-robin the plan's
+                # device_slot records
+                rec.waves = n_groups
+                self._run_sequential_single(block_ids, fn, mats,
+                                            lane_offsets, rec)
+            else:
+                items = self._wave_items(block_ids, mats, lane_offsets,
+                                         lane_shards, group_devices)
+                rec.waves = len(items)
+                for keys, dev, _ in items:
+                    self.rows_per_device[dev.id] = \
+                        self.rows_per_device.get(dev.id, 0) + len(keys)
+                if self._dec_pool is None:
+                    self._run_waves(items, wave_fn, n_blocks, rec)
+                else:
+                    self._run_overlapped(items, wave_fn, n_blocks, rec)
+            (rec.blocks_loaded, rec.blocks_stored, rec.raw_blocks_stored,
+             rec.h2d_bytes, rec.d2h_bytes) = (
+                b - a for a, b in zip(before, back.counts()))
+            if back.store is not None:
+                rec.stored_bytes = back.store.total_bytes
+        return rec
 
     # -- wave item construction ----------------------------------------------
     def _wave_items(self, block_ids, mats, lane_offsets, lane_shards,
@@ -742,7 +796,7 @@ class StagePipeline:
         return max(base, min(n_dev, len(items)))
 
     # -- sequential wave loop (depth 1 / coalescing-only hosts) ---------------
-    def _run_waves(self, items, wave_fn, n_blocks) -> None:
+    def _run_waves(self, items, wave_fn, n_blocks, rec) -> None:
         """Caller's-thread wave loop: no pools, no lookahead.  On one
         device the window is 1 — the strictly sequential reference
         schedule.  With several devices the window widens to the device
@@ -755,18 +809,13 @@ class StagePipeline:
 
         def drain():
             okeys, oticket = in_flight.popleft()
-            t0 = time.perf_counter()
-            result = back.await_result_batch(oticket)
-            self.t_fetch += time.perf_counter() - t0
-            self._store(back.store_group_batch, okeys, result)
+            result = self._await(back.await_result_batch, oticket, rec)
+            self._store(back.store_group_batch, okeys, result, rec)
 
         for keys, dev, imats in items:
-            staged = self._load(back.fetch_group_batch, keys)
-            t0 = time.perf_counter()
-            planes = back.stage_to_device_batch(staged, dev)
-            out = wave_fn(planes, *imats)
-            ticket = back.dispatch_result_batch(out, n_blocks)
-            self.t_compute += time.perf_counter() - t0
+            staged = self._load(back.fetch_group_batch, keys, rec)
+            ticket = self._dispatch(wave_fn, staged, dev, imats, n_blocks,
+                                    rec)
             in_flight.append((keys, ticket))
             if len(in_flight) >= window:
                 drain()
@@ -774,7 +823,8 @@ class StagePipeline:
             drain()
 
     # -- strictly sequential fallback (no batched stage fn) -------------------
-    def _run_sequential_single(self, block_ids, fn, mats, lane_offsets):
+    def _run_sequential_single(self, block_ids, fn, mats, lane_offsets,
+                               rec):
         """Legacy per-gate path: one group per dispatch, in order, on the
         caller's thread (kept for the side-by-side benchmark — it has no
         row-batched stage function to coalesce waves with)."""
@@ -793,19 +843,16 @@ class StagePipeline:
             group_keys = [lane_offsets[:, None] + block_ids[g][None, :]
                           for g in range(n_groups)]
         for g in range(n_groups):
-            staged = self._load(fetch, group_keys[g])
-            t0 = time.perf_counter()
-            amps_dev = to_dev(staged, self._device_for(g))
-            out = fn(amps_dev, *mats)
-            ticket = dispatch(out, n_blocks)
-            self.t_compute += time.perf_counter() - t0
-            t0 = time.perf_counter()
-            result = await_(ticket)
-            self.t_fetch += time.perf_counter() - t0
-            self._store(store, group_keys[g], result)
+            staged = self._load(fetch, group_keys[g], rec)
+            with span("pipeline.dispatch", rec):
+                amps_dev = to_dev(staged, self._device_for(g))
+                out = fn(amps_dev, *mats)
+                ticket = dispatch(out, n_blocks)
+            result = self._await(await_, ticket, rec)
+            self._store(store, group_keys[g], result, rec)
 
     # -- the double-buffered wave loop ---------------------------------------
-    def _run_overlapped(self, items, wave_fn, n_blocks) -> None:
+    def _run_overlapped(self, items, wave_fn, n_blocks, rec) -> None:
         back = self.backend
         n_waves = len(items)
         window = self._window_for(items, self.inflight_window)
@@ -820,12 +867,20 @@ class StagePipeline:
                 submitted += 1
                 fut = self._dec_pool.submit(self._load,
                                             back.fetch_group_batch,
-                                            items[w][0])
+                                            items[w][0], rec)
                 outstanding[w] = fut
                 fut.add_done_callback(lambda _f, w=w: ready.put(w))
 
         in_flight: deque = deque()     # (wave, ticket) dispatched, unawaited
         pending_save = []
+
+        def save_oldest():
+            ow, oticket = in_flight.popleft()
+            result = self._await(back.await_result_batch, oticket, rec)
+            pending_save.append(self._com_pool.submit(
+                self._store, back.store_group_batch, items[ow][0], result,
+                rec))
+
         try:
             for _ in range(min(1 + _FETCH_LOOKAHEAD, n_waves)):
                 submit_next()
@@ -833,34 +888,20 @@ class StagePipeline:
                 # completion-order ready-queue: take whichever lookahead
                 # fetch finished first — a slow decode never serializes
                 # the loop behind wave order
-                w = ready.get()
-                staged = outstanding.pop(w).result()
-                keys, dev, imats = items[w]
-                t0 = time.perf_counter()
-                planes = back.stage_to_device_batch(staged, dev)
-                out = wave_fn(planes, *imats)
-                ticket = back.dispatch_result_batch(out, n_blocks)
-                self.t_compute += time.perf_counter() - t0
+                with span("pipeline.load_wait", rec):
+                    w = ready.get()
+                    staged = outstanding.pop(w).result()
+                _, dev, imats = items[w]
+                ticket = self._dispatch(wave_fn, staged, dev, imats,
+                                        n_blocks, rec)
                 submit_next()          # keep the fetch lookahead full
                 in_flight.append((w, ticket))
                 if len(in_flight) >= window:
                     # double buffer: wave w is computing asynchronously
                     # while this (older) wave's blocking wait drains
-                    ow, oticket = in_flight.popleft()
-                    t0 = time.perf_counter()
-                    result = back.await_result_batch(oticket)
-                    self.t_fetch += time.perf_counter() - t0
-                    pending_save.append(self._com_pool.submit(
-                        self._store, back.store_group_batch,
-                        items[ow][0], result))
+                    save_oldest()
             while in_flight:           # drain the window
-                ow, oticket = in_flight.popleft()
-                t0 = time.perf_counter()
-                result = back.await_result_batch(oticket)
-                self.t_fetch += time.perf_counter() - t0
-                pending_save.append(self._com_pool.submit(
-                    self._store, back.store_group_batch,
-                    items[ow][0], result))
+                save_oldest()
         except BaseException:
             # fail fast without deadlocking the pools: drop queued
             # fetches, let running ones finish (shutdown waits), and
@@ -873,5 +914,6 @@ class StagePipeline:
                 except Exception:  # lint: disable=typed-errors -- keep original error
                     pass
             raise
-        for fut in pending_save:       # stage barrier (§4.1 semantics)
-            fut.result()
+        with span("pipeline.store_wait", rec):
+            for fut in pending_save:   # stage barrier (§4.1 semantics)
+                fut.result()

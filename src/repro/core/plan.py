@@ -124,9 +124,9 @@ class StagePlan:
 class PlanPredictions:
     """Whole-plan cost-model outputs (estimates, not measurements).
 
-    ``bytes_per_amp`` is the estimated *stored* compressed size; the
-    engine calibrates it against the first encoded stage at run time
-    (``SimStats.bytes_per_amp_measured``), with the two-level store's RAM
+    ``bytes_per_amp`` is the estimated *stored* compressed size; a run
+    measures it after every stage (each stage record's ``stored_bytes``
+    over 2^n, :mod:`repro.spans`), with the two-level store's RAM
     budget as the backstop when the estimate was optimistic.
     ``peak_ram_bytes`` predicts the store's primary-tier peak;
     ``pipeline_bytes`` the host-side staging working set on top of it.

@@ -14,9 +14,9 @@ them with a cost model under a user-supplied ``memory_budget_bytes``:
 
 The compression ratio is *estimated* from ``b_r`` (a conservative
 entropy-style model of the pwrel code stream — see
-:func:`estimate_bytes_per_amp`); the engine *calibrates* the estimate
-against the first encoded stage at run time
-(``SimStats.bytes_per_amp_measured``), and the resolved config always
+:func:`estimate_bytes_per_amp`); a run *measures* it after every stage
+(each stage record's ``stored_bytes`` over 2^n, :mod:`repro.spans`),
+and the resolved config always
 carries the budget into the two-level store's ``ram_budget_bytes`` as
 the backstop, so a mispredicted ratio spills to disk instead of
 aborting — the store guarantees ``peak_ram_bytes <= budget`` even when

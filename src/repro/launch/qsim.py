@@ -27,6 +27,7 @@ from ..core import (EngineConfig, Simulator, build_circuit,
                     with_depolarizing, zsum_cost_fn)
 from ..core.faults import INJECTION_POINTS, inject_faults
 from ..core.planner import estimate_bytes_per_amp
+from .. import spans
 from ..errors import ResumableError
 from .cache import use_compile_cache
 
@@ -293,10 +294,22 @@ def main(argv=None):
         print(f"[qsim] peak {stats.peak_total_bytes/2**20:.1f} MiB "
               f"({stats.memory_reduction:.1f}x less than standard), "
               f"spills={stats.n_spills}")
-        print(f"[qsim] total {stats.t_total:.2f}s "
-              f"(decomp {stats.t_decompress:.2f}"
-              f" compute {stats.t_compute:.2f} fetch {stats.t_fetch:.2f}"
-              f" comp {stats.t_compress:.2f})")
+        recs = spans.recent(stats.last_run)
+        stage_recs = [r for r in recs if r.stage >= 0]
+        print(f"[qsim] total {stats.t_total:.2f}s; span seconds a stage "
+              f"over {len(stage_recs)} stage(s): "
+              + ", ".join(f"{k} {v:.4f}" for k, v in
+                          sorted(spans.per_stage_s(recs).items())))
+        stored = sum(r.blocks_stored for r in stage_recs)
+        raw = sum(r.raw_blocks_stored for r in stage_recs)
+        lane_counts = sorted({r.lanes for r in stage_recs})
+        print(f"[qsim] stage records: lanes a stage "
+              f"{','.join(map(str, lane_counts)) or '-'}, "
+              f"{sum(r.groups for r in stage_recs)} groups in "
+              f"{sum(r.waves for r in stage_recs)} waves; blocks "
+              f"{sum(r.blocks_loaded for r in stage_recs)} loaded, "
+              f"{stored} stored, {raw} of them RAW "
+              f"({100 * raw / max(stored, 1):.2f}% incompressible)")
         print(f"[qsim] group transposes: {stats.n_transposes_scheduled} "
               f"scheduled vs {stats.n_transposes_naive} per-gate")
         print(f"[qsim] boundary traffic ({args.codec_backend} codec): "

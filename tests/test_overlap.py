@@ -94,7 +94,7 @@ class RecordingBackend(CodecBackend):
         return hits[0]
 
     # -- batch phase hooks (all the wave scheduler calls) ---------------------
-    def fetch_group_batch(self, key_rows):
+    def fetch_group_batch(self, key_rows, rec=None):
         wid = int(key_rows[0, 0])
         self._enter("fetch", wid)
         exc = self.fetch_raises.get(wid)
@@ -123,7 +123,7 @@ class RecordingBackend(CodecBackend):
         self.add_bytes(d2h=arr.nbytes)
         return (wid, arr)
 
-    def store_group_batch(self, key_rows, results):
+    def store_group_batch(self, key_rows, results, rec=None):
         wid, arr = results
         self._enter("store", wid)
         with self._data_lock:

@@ -5,6 +5,7 @@ import pytest
 
 from repro import (EngineConfig, ExecutionPlan, Simulator, build_circuit,
                    qaoa_template, random_circuit)
+from repro import spans
 from repro.core.planner import estimate_bytes_per_amp, resolve_config
 from repro.launch import qsim
 
@@ -149,7 +150,8 @@ def test_planner_respects_budget_qft(n, budget_kib):
         stats = sim.stats
     assert stats.peak_ram_bytes <= budget
     assert stats.n_spills == 0
-    assert 0.0 < stats.bytes_per_amp_measured <= 8.0
+    stored = spans.recent(stats.last_run)[-1].stored_bytes
+    assert 0.0 < stored / 2 ** n <= 8.0
 
 
 def test_unsatisfiable_budget_warns_and_spills():
