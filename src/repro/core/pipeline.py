@@ -35,11 +35,15 @@ double-buffered**:
   wave *w*'s result is only awaited after wave *w+1*'s compute and encode
   have been dispatched, so the await overlaps the next wave's device work
   under JAX's async dispatch.
-* the host codec halves run on small worker pools behind a completion
+* the host codec halves run on worker pools behind a completion
   **ready-queue**: fetches are submitted ahead (bounded lookahead) and the
   compute loop consumes them in *completion* order, so one slow decode
-  never serializes the loop; compressed writes drain through the store
-  pool and are barriered per stage.
+  never serializes the loop.  Each awaited wave fans out into one store
+  task per block (:meth:`CodecBackend.split_store_batch`), which the
+  store pool deflates at once — one worker per core left after the
+  dispatch thread and the fetch workers — and the stage barriers on
+  them all.  zlib releases the GIL, so the blocks' deflates run in
+  parallel and store the same bytes a single worker would.
 
 ``depth=1`` degenerates to a strictly sequential
 fetch→stage→compute→await→store loop on the caller's thread (no pools, no
@@ -61,7 +65,7 @@ import queue
 import threading
 import warnings
 from collections import deque
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 
 import jax
 import jax.numpy as jnp
@@ -298,6 +302,14 @@ class CodecBackend:
         for row, res in zip(key_rows, results):
             self.store_group(row, res, rec)
 
+    def split_store_batch(self, key_rows: np.ndarray, results) -> list:
+        """Dispatch thread: a wave's awaited results -> independent
+        ``(key_rows, results)`` store units, each a valid
+        :meth:`store_group_batch` call, that the store pool may run at
+        once.  The generic form keeps the wave whole; the codec backends
+        split it per block."""
+        return [(key_rows, results)]
+
 
 class HostCodecBackend(CodecBackend):
     """Baseline: the full codec runs on the host (seed engine behavior).
@@ -365,6 +377,15 @@ class HostCodecBackend(CodecBackend):
 
     # store_group_batch: the base per-row loop is already right — the
     # host encode is per-block CPU work with nothing to batch
+
+    def split_store_batch(self, key_rows, results):
+        # one unit per block: its key and its slice of the (R, 2^(b+m))
+        # result (a view, no copy)
+        bsz = self.bsz
+        return [(key_rows[r:r + 1, i:i + 1],
+                 results[r:r + 1, i * bsz:(i + 1) * bsz])
+                for r in range(key_rows.shape[0])
+                for i in range(key_rows.shape[1])]
 
 
 class DeviceCodecBackend(CodecBackend):
@@ -490,6 +511,12 @@ class DeviceCodecBackend(CodecBackend):
         return [wire[r * n_blocks:(r + 1) * n_blocks]
                 for r in range(rows)]
 
+    def split_store_batch(self, key_rows, results):
+        # one unit per block: its key and its wire pair
+        return [(key_rows[r:r + 1, i:i + 1], [row[i:i + 1]])
+                for r, row in enumerate(results)
+                for i in range(key_rows.shape[1])]
+
 
 def make_backend(name: str, store: BlockStore, params: PwRelParams,
                  bsz: int, compression: bool = True,
@@ -531,8 +558,8 @@ class StagePipeline:
     device→host wait (``await_result``) runs *after* wave *w+1*'s compute
     and encode dispatches, so it hides under device work, while the host
     codec halves run on the fetch/store worker pools behind a completion
-    ready-queue (see the module docs).  ``depth=1`` is the strictly
-    sequential reference schedule.
+    ready-queue, the store pool one task per block (see the module docs).
+    ``depth=1`` is the strictly sequential reference schedule.
 
     Use as a context manager (owns the worker pools); call
     :meth:`run_stage` once per partition stage.  Each call returns the
@@ -573,6 +600,10 @@ class StagePipeline:
         self.rows_per_device: dict[int, int] = {}
         self._dec_pool: ThreadPoolExecutor | None = None
         self._com_pool: ThreadPoolExecutor | None = None
+        # store calls running now, for the stage record's
+        # store_peak_inflight
+        self._stores_running = 0               # guarded-by: _stores_lock
+        self._stores_lock = threading.Lock()
         self._entered = False
 
     def __enter__(self) -> "StagePipeline":
@@ -583,14 +614,18 @@ class StagePipeline:
         # actual dispatch-amortization win — doesn't need threads.  The
         # fetch pool is lookahead-wide so the ready-queue can consume
         # waves in completion order (a slow decode never serializes the
-        # loop); one store worker drains the encode queue.
+        # loop); the store pool takes every core the dispatch thread and
+        # the fetch workers leave, so a wave's per-block deflates run at
+        # once (its threads start only as tasks queue up).
         if self.depth > 1:
+            cores = os.cpu_count() or 1
             nw = self.fetch_workers
-            if nw is None and (os.cpu_count() or 1) > 1:
-                nw = min(_FETCH_LOOKAHEAD, os.cpu_count() - 1)
+            if nw is None and cores > 1:
+                nw = min(_FETCH_LOOKAHEAD, cores - 1)
             if nw:
                 self._dec_pool = ThreadPoolExecutor(max_workers=nw)
-                self._com_pool = ThreadPoolExecutor(max_workers=1)
+                self._com_pool = ThreadPoolExecutor(
+                    max_workers=max(1, cores - 1 - nw))
         self._entered = True
         return self
 
@@ -603,12 +638,16 @@ class StagePipeline:
 
     # -- phase wrappers (the host halves run inside worker threads) -----------
     @staticmethod
-    def _key_span(keys) -> str:
+    def _key_context(keys) -> dict:
+        """The :class:`StoreIOError` arguments that name ``keys``: the
+        key itself for one block, else the span of the wave's keys."""
         flat = np.asarray(keys).reshape(-1)
         if flat.size == 0:
-            return "no keys"
-        return (f"keys [{int(flat.min())}..{int(flat.max())}] "
-                f"({flat.size} blocks)")
+            return {"detail": "no keys"}
+        if flat.size == 1:
+            return {"key": int(flat[0])}
+        return {"detail": f"keys [{int(flat.min())}..{int(flat.max())}] "
+                          f"({flat.size} blocks)"}
 
     def _load(self, fetch, keys, rec):
         with span("pipeline.load", rec):
@@ -621,18 +660,26 @@ class StagePipeline:
                 # a raw OSError escaping a fetch worker carries no context
                 # — name the wave so the failure is attributable
                 raise StoreIOError("pipeline fetch",
-                                   detail=self._key_span(keys)) from e
+                                   **self._key_context(keys)) from e
 
     def _store(self, store, keys, result, rec):
-        with span("pipeline.store", rec):
-            try:
-                fault_point("pipeline.store")
-                store(keys, result, rec)
-            except (StoreIOError, BlockCorruptionError):
-                raise
-            except OSError as e:
-                raise StoreIOError("pipeline store",
-                                   detail=self._key_span(keys)) from e
+        with self._stores_lock:
+            self._stores_running += 1
+            rec.store_peak_inflight = max(rec.store_peak_inflight,
+                                          self._stores_running)
+        try:
+            with span("pipeline.store", rec):
+                try:
+                    fault_point("pipeline.store")
+                    store(keys, result, rec)
+                except (StoreIOError, BlockCorruptionError):
+                    raise
+                except OSError as e:
+                    raise StoreIOError("pipeline store",
+                                       **self._key_context(keys)) from e
+        finally:
+            with self._stores_lock:
+                self._stores_running -= 1
 
     def _dispatch(self, wave_fn, staged, dev, imats, n_blocks, rec):
         """Dispatch thread: h2d staging, the stage program and the encode
@@ -877,9 +924,10 @@ class StagePipeline:
         def save_oldest():
             ow, oticket = in_flight.popleft()
             result = self._await(back.await_result_batch, oticket, rec)
-            pending_save.append(self._com_pool.submit(
-                self._store, back.store_group_batch, items[ow][0], result,
-                rec))
+            # one store task per block: the pool deflates them at once
+            for keys, unit in back.split_store_batch(items[ow][0], result):
+                pending_save.append(self._com_pool.submit(
+                    self._store, back.store_group_batch, keys, unit, rec))
 
         try:
             for _ in range(min(1 + _FETCH_LOOKAHEAD, n_waves)):
@@ -915,5 +963,8 @@ class StagePipeline:
                     pass
             raise
         with span("pipeline.store_wait", rec):
-            for fut in pending_save:   # stage barrier (§4.1 semantics)
+            # stage barrier (§4.1 semantics): every block's store ends
+            # before the stage returns, then the first failure raises
+            wait(pending_save)
+            for fut in pending_save:
                 fut.result()

@@ -17,7 +17,8 @@ Registered points (see ARCHITECTURE.md "Resilience layer"):
 ``codec.encode``      every host/device block-encode dispatch
 ``codec.decode``      every host/device block-decode dispatch
 ``pipeline.fetch``    every pipeline fetch-worker step (one wave/group)
-``pipeline.store``    every pipeline store-worker step (one wave/group)
+``pipeline.store``    every pipeline store step (one block in the store
+                      pool; one wave/group on the caller's thread)
 ``pipeline.exchange`` every cross-device block hand-off (one block moving
                       owners between stages of a block-sharded run)
 ``checkpoint.write``  every store snapshot (once per checkpoint)

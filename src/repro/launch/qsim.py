@@ -309,7 +309,9 @@ def main(argv=None):
               f"{sum(r.waves for r in stage_recs)} waves; blocks "
               f"{sum(r.blocks_loaded for r in stage_recs)} loaded, "
               f"{stored} stored, {raw} of them RAW "
-              f"({100 * raw / max(stored, 1):.2f}% incompressible)")
+              f"({100 * raw / max(stored, 1):.2f}% incompressible); "
+              f"store peak in flight "
+              f"{max((r.store_peak_inflight for r in stage_recs), default=0)}")
         print(f"[qsim] group transposes: {stats.n_transposes_scheduled} "
               f"scheduled vs {stats.n_transposes_naive} per-gate")
         print(f"[qsim] boundary traffic ({args.codec_backend} codec): "

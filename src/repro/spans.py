@@ -28,7 +28,8 @@ dispatch       ``stage`` > ``pipeline.load_wait`` (a fetched wave from the
                ``pipeline.store_wait`` (the stage barrier on the store
                pool); ``run.init``
 load worker    ``pipeline.load`` > ``store.get``, ``codec.inflate``
-store worker   ``pipeline.store`` > ``codec.deflate``, ``store.put``
+store worker   ``pipeline.store`` > ``codec.deflate``, ``store.put`` (one
+               block a call; the workers run them at once)
 =============  ==============================================================
 
 Without worker pools (``pipeline_depth`` 1 or a single-core host) the
@@ -75,6 +76,9 @@ class StageRecord:
     written to the store, the written blocks that took the codec's RAW
     escape (incompressible), bytes across the host-device boundary each
     way, and the store's total bytes when the stage ended.
+    ``store_peak_inflight`` is the most store calls that ran at once in
+    the stage: 1 where the dispatch thread stores (depth 1, no pools),
+    up to the store pool's width where each block is its own task.
     """
 
     run: int
@@ -90,6 +94,7 @@ class StageRecord:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     stored_bytes: int = 0
+    store_peak_inflight: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
 

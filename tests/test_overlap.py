@@ -324,3 +324,164 @@ def test_host_backend_ledger_exact_under_concurrent_phase_hooks():
     assert back.h2d_bytes == per_xfer * n_threads * n_iter
     assert back.d2h_bytes == per_xfer * n_threads * n_iter
     assert back.n_decompressions == n_threads * n_iter
+
+
+# -- per-block store fan-out --------------------------------------------------
+#
+# The overlapped scheduler submits one store task per block of an awaited
+# wave (``split_store_batch``), and the store pool runs them at once.  The
+# reference is the strictly sequential depth-1 schedule, which stores one
+# group a call on the caller's thread.
+
+def _fan_out_run(monkeypatch, backend, batched, depth, fetch_workers,
+                 rendezvous=False):
+    """Run qft-10 (16 groups of 4 blocks) on a Simulator whose pipelines
+    use ``fetch_workers``; returns (lane states, stored blobs, stage
+    records, the set of block counts the store calls took).
+    ``rendezvous`` makes the first two store calls of the run wait for
+    each other, so they must run at once."""
+    import functools
+
+    from repro import EngineConfig, Simulator, build_circuit, spans
+    from repro.core import engine as engine_mod
+    from repro.core import pipeline as pipeline_mod
+
+    monkeypatch.setattr(
+        engine_mod, "StagePipeline",
+        functools.partial(StagePipeline, fetch_workers=fetch_workers))
+    # a store pool at least two wide on any host: 8 cores less the
+    # dispatch thread and the fetch workers
+    monkeypatch.setattr(pipeline_mod.os, "cpu_count", lambda: 8)
+    cls = (pipeline_mod.DeviceCodecBackend if backend == "device"
+           else HostCodecBackend)
+    meet = threading.Barrier(2, timeout=_TIMEOUT)
+    calls = iter(range(2 if rendezvous else 0))
+    sizes = set()
+    real = cls.store_group
+
+    def store_group(self, block_ids, result, rec=None):
+        sizes.add(len(block_ids))
+        if next(calls, None) is not None:
+            meet.wait()              # BrokenBarrierError if run alone
+        return real(self, block_ids, result, rec)
+    monkeypatch.setattr(cls, "store_group", store_group)
+    cfg = EngineConfig(local_bits=4, inner_size=2, b_r=1e-3,
+                       codec_backend=backend, pipeline_depth=depth)
+    with Simulator(build_circuit("qft", 10), cfg) as sim:
+        if batched:
+            states = [np.asarray(lane.statevector())
+                      for lane in sim.run_batch([None] * 2)]
+        else:
+            states = [np.asarray(sim.run().statevector())]
+        store = sim._engine.store
+        blobs = {k: store.get(k) for k in store.keys()}
+        recs = [r for r in spans.recent(sim.stats.last_run) if r.stage >= 0]
+    monkeypatch.undo()
+    return states, blobs, recs, sizes
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_per_block_stores_run_at_once_and_store_the_same_bytes(
+        monkeypatch, backend, batched):
+    fanned = _fan_out_run(monkeypatch, backend, batched, depth=2,
+                          fetch_workers=2, rendezvous=True)
+    depth1 = _fan_out_run(monkeypatch, backend, batched, depth=1,
+                          fetch_workers=2)
+    states, blobs, recs, sizes = fanned
+    assert recs and all(r.waves > 1 for r in recs)
+    assert max(r.store_peak_inflight for r in recs) > 1
+    assert all(r.store_peak_inflight == 1 for r in depth1[2])
+    # one block a store call in the pool; a group a call on the caller's
+    # thread
+    assert sizes == {1} and depth1[3] == {4}
+    # the same bytes under every key, and the same decoded state
+    assert blobs.keys() == depth1[1].keys()
+    for k in blobs:
+        assert blobs[k] == depth1[1][k], f"block {k} differs"
+    for a, b in zip(states, depth1[0], strict=True):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("point", ["pipeline.store", "codec.encode"])
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_failed_block_store_names_its_key(backend, point):
+    """A fault in one block's store task raises the typed error, naming
+    that block's key: its blob is the only one the stage left as it was,
+    because the barrier lets every other block's store finish first."""
+    jax = pytest.importorskip("jax")
+    from repro.compression.pwrel import PwRelParams
+    from repro.compression.store import BlockStore
+    from repro.core.faults import inject_faults
+    from repro.core.pipeline import make_backend
+    from repro.errors import StoreIOError
+
+    bsz, n_groups, n_blocks = 64, 4, 2
+    back = make_backend(backend, BlockStore(), PwRelParams(1e-3), bsz)
+    rng = np.random.default_rng(5)
+    for k in range(n_groups * n_blocks):
+        back.encode_host_block(k, (rng.standard_normal(bsz) + 1j
+                                   * rng.standard_normal(bsz))
+                               .astype(np.complex64))
+    before = {k: back.store.get(k) for k in back.store.keys()}
+    block_ids = np.arange(n_groups * n_blocks).reshape(n_groups, n_blocks)
+    pipe = StagePipeline(back, depth=2, fetch_workers=2)
+    with inject_faults([f"{point}:ioerror:hit=3"]) as inj:
+        with pytest.raises(StoreIOError) as err:
+            with pipe:
+                pipe.run_stage(block_ids, fn=None, mats=[],
+                               wave_fn=jax.jit(lambda p: p * 2.0))
+    assert inj.fired[f"{point}:ioerror"] == 1
+    assert pipe._dec_pool is None and pipe._com_pool is None
+    failed = err.value.key
+    assert f"pipeline store failed key={failed}" in str(err.value)
+    unchanged = {k for k in before if back.store.get(k) == before[k]}
+    assert unchanged == {failed}
+
+
+def test_store_fan_out_stress_keeps_every_block(monkeypatch):
+    """Many small block stores on a store pool wider than the host, with
+    a very short thread switch interval: every block is stored with the
+    same bytes as the one-wave-at-a-time loop, and the in-flight gauge
+    returns to zero within the pool's width."""
+    import sys
+
+    import jax
+
+    from repro.compression.pwrel import PwRelParams
+    from repro.compression.store import BlockStore
+    from repro.core import pipeline as pipeline_mod
+
+    bsz, n_groups, n_blocks = 32, 32, 4
+    monkeypatch.setattr(pipeline_mod.os, "cpu_count", lambda: 16)
+    rng = np.random.default_rng(11)
+    amps = (rng.standard_normal((n_groups * n_blocks, bsz))
+            + 1j * rng.standard_normal((n_groups * n_blocks, bsz))) \
+        .astype(np.complex64)
+    block_ids = np.arange(n_groups * n_blocks).reshape(n_groups, n_blocks)
+    wave_fn = jax.jit(lambda p: p * 0.5)
+
+    def stage(fetch_workers):
+        back = HostCodecBackend(BlockStore(), PwRelParams(1e-3), bsz)
+        for k, a in enumerate(amps):
+            back.encode_host_block(k, a)
+        with StagePipeline(back, depth=4, fetch_workers=fetch_workers) as p:
+            rec = p.run_stage(block_ids, fn=None, mats=[], wave_fn=wave_fn)
+            width = p._com_pool._max_workers if p._com_pool else 1
+            with p._stores_lock:
+                running = p._stores_running
+        return back, rec, width, running
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        fanned, rec, width, running = stage(fetch_workers=2)
+    finally:
+        sys.setswitchinterval(old)
+    serial, ref_rec, _, _ = stage(fetch_workers=0)
+    assert width == 13 and running == 0
+    assert 1 <= rec.store_peak_inflight <= width
+    assert ref_rec.store_peak_inflight == 1
+    assert rec.blocks_stored == ref_rec.blocks_stored == n_groups * n_blocks
+    for k in range(n_groups * n_blocks):
+        assert fanned.store.get(k) == serial.store.get(k), f"block {k}"

@@ -350,7 +350,7 @@ def test_crash_resume_equivalence_every_boundary(backend, tmp_path):
     ("codec.encode", 60),
     ("codec.decode", 60),
     ("pipeline.fetch", 30),
-    ("pipeline.store", 30),
+    ("pipeline.store", 60),          # one hit a block, as codec.encode
     ("checkpoint.write", 3),
 ])
 def test_every_point_crash_is_resumable(point, hit, ref9, tmp_path):

@@ -269,7 +269,8 @@ def test_qsim_prints_the_run_spans_and_counters(capsys):
             f"{sum(r.groups for r in stages)} groups in "
             f"{sum(r.waves for r in stages)} waves; blocks "
             f"{sum(r.blocks_loaded for r in stages)} loaded, {stored} "
-            "stored, 0 of them RAW (0.00% incompressible)") in out
+            "stored, 0 of them RAW (0.00% incompressible); store peak in "
+            f"flight {max(r.store_peak_inflight for r in stages)}") in out
     span_line = [ln for ln in out if "span seconds a stage" in ln]
     assert len(span_line) == 1
     for name in {k for r in stages for k in r.span_s}:
