@@ -9,8 +9,27 @@ found by name:
   the program's circuit, draws a job's binding from the seed and lists
   the textbook gates the reference applies;
 * a traffic mix is ``traffic/<traffic>.json``: ``mode`` (``run``, one
-  state a job, or ``run_batch``, ``lanes`` states a job);
+  state a job; ``run_batch``, ``lanes`` states a job; ``trajectories``,
+  ``lanes`` noise trajectories of one binding a job);
 * a metric is ``metrics/<name>.py`` with ``read(ctx) -> float | None``.
+
+A family module has ``program_circuit(cfg)``, ``draw(cfg, rng)`` (a
+job's binding, or None) and ``gates(cfg, params)``: the reference's
+``(name, qubits, args)`` list (``reference.py`` gives the gates and
+their qubit order).  A family of a stochastic circuit, run in
+``trajectories`` mode, takes ``gates(cfg, params, seed)`` and returns
+the gates of the lane that the program realizes with that seed.  It
+realizes the channels itself, from the contract ``Circuit.realize``
+documents, without the program's code: ``rng =
+np.random.default_rng(seed)``; one ``rng.random()`` u a channel, in
+circuit order; the channel becomes the first outcome of its table
+``((probability, gate), ...)`` whose running sum of probabilities,
+added in table order, exceeds u, or the last outcome if none does.
+
+A ``trajectories`` job draws the binding (``family.draw``) and then a
+base seed s from the run's rng, so ``--seed`` reproduces the stream and
+each job realizes fresh trajectories; it runs ``Simulator.run(params,
+trajectories=lanes, seed=s)``, whose lane j draws with seed s + j.
 
 A run, in order: set up the session and warm it with one whole job
 (``setup_s``); drive jobs back to back and close the window at the first
@@ -36,9 +55,10 @@ import numpy as np
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 
-__all__ = ["BENCH_DIR", "ROOT", "Cell", "load_benchmark", "load_cell",
-           "CompileClock", "ProgramRunner", "ControlRunner", "compare",
-           "run_cell", "cell_metrics", "read_metric"]
+__all__ = ["BENCH_DIR", "ROOT", "Cell", "Trajectory", "load_benchmark",
+           "load_cell", "CompileClock", "ProgramRunner", "ControlRunner",
+           "compare", "lane_gates", "run_cell", "cell_metrics",
+           "read_metric"]
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -64,6 +84,22 @@ class Cell:
     @property
     def lanes(self) -> int:
         return int(self.traffic["lanes"])
+
+
+@dataclass(frozen=True)
+class Trajectory:
+    """One lane of a ``trajectories`` job: the job's binding and the
+    seed this lane's channels are realized with (the job's base seed
+    plus the lane's index)."""
+    params: dict | None
+    seed: int
+
+
+def lane_gates(cell: Cell, binding) -> list:
+    """The reference's gate list for one lane of a job."""
+    if isinstance(binding, Trajectory):
+        return cell.family.gates(cell.cfg, binding.params, seed=binding.seed)
+    return cell.family.gates(cell.cfg, binding)
 
 
 def load_cell(bench: dict, name: str, root: Path = ROOT) -> Cell:
@@ -137,8 +173,13 @@ class ProgramRunner:
         self._result = None
 
     def job(self, bindings: list) -> None:
-        if self.cell.traffic["mode"] == "run":
+        mode = self.cell.traffic["mode"]
+        if mode == "run":
             self._result = [self.sim.run(bindings[0])]
+        elif mode == "trajectories":
+            first = bindings[0]
+            self._result = list(self.sim.run(
+                first.params, trajectories=len(bindings), seed=first.seed))
         else:
             self._result = list(self.sim.run_batch(bindings))
 
@@ -154,7 +195,9 @@ class ProgramRunner:
 
     def plan_stages(self, bindings: list):
         """``(fused, m, n_groups)`` of each stage the job runs."""
-        plan = self.sim.compile(bindings[0], verify=False)
+        first = bindings[0]
+        params = first.params if isinstance(first, Trajectory) else first
+        plan = self.sim.compile(params, verify=False)
         return [(sp.plan, sp.layout.m, sp.n_groups) for sp in plan.stages]
 
     def stats(self):
@@ -176,8 +219,8 @@ class ControlRunner:
 
     def job(self, bindings: list) -> None:
         from reference import simulate
-        self._states = [simulate(self.n, self.cell.family.gates(
-            self.cell.cfg, b), self.dtype) for b in bindings]
+        self._states = [simulate(self.n, lane_gates(self.cell, b),
+                                 self.dtype) for b in bindings]
         for s in self._states:
             s.re.block_until_ready()
 
@@ -201,7 +244,7 @@ def compare(runner, cell: Cell, bindings: list, block_bits: int = 20) -> dict:
     blk = 1 << min(block_bits, n)
     worst = {"rel_l2_err": 0.0, "fidelity": 1.0, "norm_dev": 0.0}
     for lane, b in enumerate(bindings):
-        ref = simulate(n, cell.family.gates(cell.cfg, b), jnp.float32)
+        ref = simulate(n, lane_gates(cell, b), jnp.float32)
         d2 = r2 = s2 = 0.0
         dot = 0j
         for lo in range(0, 1 << n, blk):
@@ -230,7 +273,13 @@ def _draw_job(cell: Cell, rng) -> list:
     """One job's bindings, one a lane.  With ``perturb`` c in the traffic
     mix the job is SPSA's two-point evaluation: one binding theta drawn
     by the family, and the lanes theta + c * delta and theta - c * delta
-    for a random sign vector delta."""
+    for a random sign vector delta.  In ``trajectories`` mode the lanes
+    share one binding and take the seeds s, s + 1, ... from a base seed
+    s drawn after it."""
+    if cell.traffic["mode"] == "trajectories":
+        theta = cell.family.draw(cell.cfg, rng)
+        base = int(rng.integers(0, 2 ** 31))
+        return [Trajectory(theta, base + j) for j in range(cell.lanes)]
     c = cell.traffic.get("perturb")
     if c is None:
         return [cell.family.draw(cell.cfg, rng) for _ in range(cell.lanes)]

@@ -10,7 +10,19 @@ arithmetic only, so no matrix product and no precision setting enters:
   (a roll along the lane axis for q < 7, along the row axis above) and
   forms ``U[b, 0] x[i with bit 0] + U[b, 1] x[i with bit 1]``;
 * a two-qubit diagonal gate multiplies by the entry its two bits select;
-* SWAP moves amplitudes whose two bits differ to ``i ^ 2^a ^ 2^b``.
+* SWAP moves amplitudes whose two bits differ to ``i ^ 2^a ^ 2^b``;
+* any other two-qubit gate U on (a, b) reads the three partners
+  ``i ^ 2^a``, ``i ^ 2^b``, ``i ^ 2^a ^ 2^b`` and forms
+  ``sum_m U[k, k ^ m] x[i with k ^ m]``, k the entry of i's two bits;
+* the identity ``i`` leaves the state as it is.
+
+A gate is ``(name, qubits, args)``.  A two-qubit gate's entries are
+numbered ``bit_a + 2 * bit_b`` for ``qubits = (a, b)``; the controlled
+gates take ``(control, target)``: ``("cx", (c, t))``, ``("cz", (c, t))``,
+``("cp", (c, t), (lam,))``, ``("crz", (c, t), (theta,))``.  ``swap``,
+``rzz`` and ``rxx`` are symmetric in their two qubits.  The one-qubit
+gates are ``i x y z h s sdg t tdg rx ry rz p u3``; ``u3`` takes
+``(theta, phi, lam)``.
 
 ``dtype`` is the planes' storage type and the type every operation
 rounds to: float32 for the reference; ``bench/control.py`` also reads
@@ -49,9 +61,65 @@ def _rzz_diag(theta: float) -> np.ndarray:
     return np.array([e, np.conj(e), np.conj(e), e], np.complex128)
 
 
+def _ry(theta: float) -> np.ndarray:
+    c, s = math.cos(theta / 2), math.sin(theta / 2)
+    return np.array([[c, -s], [s, c]], np.complex128)
+
+
+def _rz(theta: float) -> np.ndarray:
+    e = np.exp(-0.5j * theta)
+    return np.array([[e, 0], [0, np.conj(e)]], np.complex128)
+
+
+def _p(lam: float) -> np.ndarray:
+    return np.array([[1, 0], [0, np.exp(1j * lam)]], np.complex128)
+
+
+def _u3(theta: float, phi: float, lam: float) -> np.ndarray:
+    c, s = math.cos(theta / 2), math.sin(theta / 2)
+    return np.array([[c, -np.exp(1j * lam) * s],
+                     [np.exp(1j * phi) * s, np.exp(1j * (phi + lam)) * c]],
+                    np.complex128)
+
+
+def _crz_diag(theta: float) -> np.ndarray:
+    """(control, target): Rz(theta) on the target where the control is 1."""
+    e = np.exp(-0.5j * theta)
+    return np.array([1, e, 1, np.conj(e)], np.complex128)
+
+
+def _rxx(theta: float) -> np.ndarray:
+    """exp(-i theta/2 X X): cos on the diagonal, -i sin between entries
+    whose two bits both differ."""
+    c, s = math.cos(theta / 2), math.sin(theta / 2)
+    u = np.eye(4, dtype=np.complex128) * c
+    for k in range(4):
+        u[k, k ^ 3] = -1j * s
+    return u
+
+
+#: (control, target): entries 1 (control set, target clear) and 3 swap
+_CX = np.eye(4, dtype=np.complex128)[[0, 3, 2, 1]]
+
+_FIXED = {
+    "i": np.eye(2, dtype=np.complex128),
+    "y": np.array([[0, -1j], [1j, 0]], np.complex128),
+    "z": np.diag([1, -1]).astype(np.complex128),
+    "s": np.diag([1, 1j]).astype(np.complex128),
+    "sdg": np.diag([1, -1j]).astype(np.complex128),
+    "t": np.diag([1, np.exp(0.25j * math.pi)]).astype(np.complex128),
+    "tdg": np.diag([1, np.exp(-0.25j * math.pi)]).astype(np.complex128),
+    "cz": np.array([1, 1, 1, -1], np.complex128),
+    "cx": _CX,
+}
+_ROTATIONS = {"ry": _ry, "rz": _rz, "p": _p, "u3": _u3, "crz": _crz_diag,
+              "rxx": _rxx}
+
+
 def gate_matrix(name: str, args: tuple) -> np.ndarray:
-    """The 2x2 matrix of a one-qubit gate, or the 4 diagonal entries of
-    a two-qubit diagonal gate (entry ``bit_a + 2 * bit_b``)."""
+    """The 2x2 matrix of a one-qubit gate, the 4 diagonal entries of a
+    two-qubit diagonal gate, or the 4x4 matrix of another two-qubit
+    gate (entry ``bit_a + 2 * bit_b``)."""
     if name == "h":
         return H
     if name == "x":
@@ -62,6 +130,10 @@ def gate_matrix(name: str, args: tuple) -> np.ndarray:
         return _cp_diag(*args)
     if name == "rzz":
         return _rzz_diag(*args)
+    if name in _FIXED:
+        return _FIXED[name]
+    if name in _ROTATIONS:
+        return _ROTATIONS[name](*args)
     raise KeyError(f"the reference has no gate {name!r}")
 
 
@@ -121,6 +193,24 @@ def _swap(re, im, a: int, b: int):
             jnp.where(same, im, _flip(_flip(im, a), b)))
 
 
+@partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0, 1))
+def _two_qubit(re, im, u, a: int, b: int):
+    """u: (2, 4, 4) [re/im, row, col], entries ``bit_a + 2 bit_b``."""
+    k = _index_bit(re.shape, a) + 2 * _index_bit(re.shape, b)
+    partners = ((re, im), (_flip(re, a), _flip(im, a)),
+                (_flip(re, b), _flip(im, b)),
+                (_flip(_flip(re, a), b), _flip(_flip(im, a), b)))
+    out_r = out_i = 0.0
+    for m, (pr, pi) in enumerate(partners):
+        # the entry U[k, k ^ m] that weighs partner m of an amplitude
+        cr, ci = (jnp.select([k == 0, k == 1, k == 2],
+                             [u[p, 0, m], u[p, 1, 1 ^ m], u[p, 2, 2 ^ m]],
+                             u[p, 3, 3 ^ m]) for p in (0, 1))
+        tr, ti = _cmul(cr, ci, pr, pi)
+        out_r, out_i = out_r + tr, out_i + ti
+    return out_r, out_i
+
+
 def _planes_of(m: np.ndarray, dtype) -> jax.Array:
     return jnp.asarray(np.stack([m.real, m.imag]), dtype)
 
@@ -138,6 +228,8 @@ class Reference:
         self.im = jnp.zeros((rows, _LANES), dtype)
 
     def apply(self, name: str, qubits: tuple, args: tuple = ()) -> None:
+        if name == "i":
+            return
         if name == "swap":
             a, b = qubits
             self.re, self.im = _swap(self.re, self.im, a, b)
@@ -145,10 +237,13 @@ class Reference:
             u = _planes_of(gate_matrix(name, args), self.dtype)
             self.re, self.im = _one_qubit(self.re, self.im, u, qubits[0])
         else:
-            d = _planes_of(gate_matrix(name, args), self.dtype)
+            m = _planes_of(gate_matrix(name, args), self.dtype)
             a, b = qubits
-            self.re, self.im = _diag_two(self.re, self.im, d,
-                                         jnp.int32(a), jnp.int32(b))
+            if m.ndim == 2:
+                self.re, self.im = _diag_two(self.re, self.im, m,
+                                             jnp.int32(a), jnp.int32(b))
+            else:
+                self.re, self.im = _two_qubit(self.re, self.im, m, a, b)
 
     def block(self, lo: int, hi: int) -> np.ndarray:
         """Amplitudes ``lo`` to ``hi`` (whole 128-lane rows) as complex128
